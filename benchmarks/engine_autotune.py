@@ -74,10 +74,13 @@ def time_backend(fn, *args, iters: int = 3) -> float:
 
 def tune(
     x_bits_list, group_size: int, lut_cell_limit: int, quick: bool, iters: int
-) -> dict:
+) -> tuple:
+    """Time every eligible backend per bucket: ``(table, failures)``, where
+    ``failures`` lists the ``(bucket, backend)`` pairs that raised."""
     rng = np.random.default_rng(0)
     shapes = QUICK_SHAPES if quick else BUCKET_SHAPES
     table: dict = {}
+    failures: list = []
     for x_bits in x_bits_list:
         cfg = DAConfig(group_size=group_size, x_bits=x_bits, x_signed=True)
         for cell, (m, k, n) in shapes.items():
@@ -96,11 +99,12 @@ def tune(
                         time_backend(fn, xj, packed, iters=iters), 1)
                 except Exception as e:  # noqa: BLE001 — record, keep tuning
                     print(f"  {bucket} {spec.name}: failed ({e})")
+                    failures.append((bucket, spec.name))
             table[bucket] = costs
             best = min(costs, key=costs.get) if costs else "-"
             pretty = ", ".join(f"{b}={us:.0f}us" for b, us in costs.items())
             print(f"{bucket:12s} ({m}x{k}x{n}): {pretty}  -> {best}")
-    return table
+    return table, failures
 
 
 def main():
@@ -119,8 +123,14 @@ def main():
                     help="cache path (default: engine default_cache_path())")
     args = ap.parse_args()
 
-    table = tune(args.x_bits, args.group_size, args.lut_cell_limit, args.quick,
-                 args.iters)
+    table, failures = tune(args.x_bits, args.group_size, args.lut_cell_limit,
+                           args.quick, args.iters)
+    if failures:
+        # a table missing a backend that failed would silently rank it out
+        raise SystemExit(
+            "engine_autotune: backends failed to compile or run: "
+            + ", ".join(f"{name} ({bucket})" for bucket, name in failures)
+            + "; cache not written")
     out = args.out or default_cache_path()
     payload = {
         # provenance stamp (git sha, seed, device, interpret flag, schema

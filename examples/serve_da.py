@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import count_params, init_model
 from repro.serve.engine import Request, ServeEngine
 from repro.core.freeze import da_memory_report
@@ -67,6 +68,7 @@ def print_plan(eng):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
@@ -186,7 +188,8 @@ def main():
     dt = time.perf_counter() - t0
     total_toks = sum(len(r.generated) for r in done.values())
     print(f"\nserved {len(done)} requests / {total_toks} tokens in {dt:.1f}s "
-          f"({total_toks/dt:.1f} tok/s on CPU, continuous batching, "
+          f"({total_toks/dt:.1f} tok/s on {jax.devices()[0].platform}, "
+          f"continuous batching, "
           f"runtime={eng.runtime}, batch={args.batch})")
     sm = eng.metrics().get("spec")
     if sm:

@@ -125,15 +125,14 @@ def _is_ref(var: Any) -> bool:
 
 
 def _where(eqn: Any) -> str:
-    try:
-        from jax._src import source_info_util
+    # no try/except: a changed private API must fail loudly, not silently
+    # empty every location the allowlist matches on
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return ""
-        return f"{frame.file_name}:{frame.start_line}"
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return ""
+    return f"{frame.file_name}:{frame.start_line}"
 
 
 def _literal_is_one(atom: Any) -> bool:

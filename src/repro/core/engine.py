@@ -475,6 +475,9 @@ def default_cache_path() -> pathlib.Path:
 
 _COST_TABLE: Optional[Dict[str, Dict[str, float]]] = None  # None → not loaded
 
+#: (cache path, tuned-on device) pairs already warned about as foreign.
+_DEVICE_MISMATCH_WARNED: set = set()
+
 
 def load_cost_table(path: Optional[os.PathLike] = None) -> Dict[str, Dict[str, float]]:
     """Lazily load the autotune cache: {bucket: {backend: µs}}.
@@ -501,8 +504,19 @@ def load_cost_table(path: Optional[os.PathLike] = None) -> Dict[str, Dict[str, f
         raw = json.loads(p.read_text())
         entries = raw.get("table", raw)
         device = raw.get("device") if isinstance(raw, dict) else None
-        if device is not None and device != jax.default_backend():
-            entries = {}  # tuned on different hardware: fall back to heuristic
+        here = jax.default_backend()
+        if device is not None and device != here:
+            # tuned on different hardware: fall back to the heuristic
+            entries = {}
+            if (str(p), device) not in _DEVICE_MISMATCH_WARNED:
+                _DEVICE_MISMATCH_WARNED.add((str(p), device))
+                warnings.warn(
+                    f"autotune cache {p} was tuned on {device!r} but this "
+                    f"process runs on {here!r}; ignoring it — dispatch uses "
+                    "the heuristic until benchmarks/engine_autotune.py is "
+                    f"run on {here!r} (warned once)",
+                    stacklevel=2,
+                )
         stamp = raw.get("registry") if isinstance(raw, dict) else None
         if stamp is not None and stamp != registry_fingerprint():
             warnings.warn(
@@ -890,7 +904,7 @@ def _gather_attn_backend(q, k_pool, v_pool, page_table, tpos, **kw):
 
 @register_attn_backend(
     "fused",
-    description="Pallas kernel: in-kernel page walk + online softmax "
+    description="Pallas kernel: in-kernel page walk, per-head softmax "
     "(interpret off-TPU)",
 )
 def _fused_attn_backend(q, k_pool, v_pool, page_table, tpos, **kw):

@@ -17,7 +17,9 @@ in-register. K is the reduction axis (output revisited, init at k == 0).
 Exactness: each per-tile dot is a {0,1}-plane against weight codes, so its
 value is ≤ bk·max|w| — kept < 2²⁴ (fp32's exact-integer range) by shrinking
 the K tile to fit the actual weight-code magnitude (:func:`_fit_bk`); the
-int32 accumulator covers the full growth.
+dot runs at ``Precision.HIGHEST`` so the MXU keeps float32 products of codes
+wider than bfloat16's 8-bit significand; the int32 accumulator covers the
+full growth.
 """
 from __future__ import annotations
 
@@ -42,7 +44,8 @@ def _bitplane_kernel(x_ref, w_ref, out_ref, *, cfg: DAConfig):
     acc = jnp.zeros(out_ref.shape, dtype=jnp.int32)
     for b in range(cfg.x_bits):  # unrolled bit-serial cycles
         plane = jnp.bitwise_and(jnp.right_shift(xm, b), 1).astype(jnp.float32)
-        mr = jnp.dot(plane, w, preferred_element_type=jnp.float32)
+        mr = jnp.dot(plane, w, preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
         acc = acc + jnp.int32(coefs[b]) * mr.astype(jnp.int32)
 
     @pl.when(k_idx == 0)

@@ -1,27 +1,35 @@
 """Pallas TPU kernel: Distributed-Arithmetic VMM with in-VMEM LUT readout.
 
-TPU-native mapping of the paper's PMA datapath (DESIGN.md §2):
+TPU-native mapping of the paper's PMA datapath:
 
-  * the PMA *address decoder* (8-bit address → 1-of-256 wordline) becomes an
-    in-register one-hot expansion ``iota == addr``;
+  * the PMA *address decoder* (L input bits → 1-of-2^L wordline) becomes an
+    MXU pass plus a compare: the bit-plane ``[bm, bg·L]`` times a constant
+    0/2^i expansion matrix ``[bg·L, bg·2^L]`` places each group's address
+    ``Σ_i bit_i·2^i`` on all 2^L columns of that group, and comparing with
+    the column's own address gives the one-hot wordlines;
   * the *array readout + inter-PMA adder tree* becomes a single MXU matmul
-    ``onehot[bm, G·256] @ LUT[G·256, bn]`` — the systolic array sums the
+    ``onehot[bm, bg·2^L] @ LUT[bg·2^L, bn]`` — the systolic array sums the
     selected weight-sum rows of every PMA group in one pass;
   * the *bit-serial shift-and-add accumulator* becomes an unrolled loop over
-    the 8 bit-planes with int32 accumulation (covers the 21-bit growth).
+    the bit-planes with int32 accumulation (covers the 21-bit growth).
 
 Tiling: grid = (M/bm, N/bn, G/bg); the LUT is streamed through VMEM in
-``bg``-group chunks of shape [bg·256, bn] (bg=8, bn=256 → 2 MB int32, well
-within the ~16 MB VMEM budget together with the [bm, bg·8] input tile and the
-[bm, bn] int32 accumulator). The G axis is the reduction dimension — the
-output block is revisited and accumulated, initialized at g == 0.
+``bg``-group chunks of shape [bg·2^L, bn]. By default ``bg·L`` is the
+smallest multiple of the 128-lane vreg width (bg=16 at L=8 → a [4096, 128]
+int32 LUT block, 2 MB), or all groups when K is smaller. The G axis is the
+reduction dimension — the output block is revisited and accumulated,
+initialized at g == 0.
 
-Exactness: one-hot (0/1) × LUT entries (|·| ≤ group·127 ≤ 2¹¹) dot products
-stay far below 2²⁴, so the fp32 MXU pass is exact; accumulation is int32.
+Exactness: the address pass sums at most L products of a bit with a power of
+two below 2^L, exact in bfloat16 operands with float32 accumulation. The
+readout pass selects one LUT entry per group (|entry| ≤ L·127, past
+bfloat16's 8-bit significand), so it runs at ``Precision.HIGHEST`` — float32
+products — and its sums stay below bg·L·127 < 2²⁴; accumulation is int32.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -29,32 +37,32 @@ from jax.experimental import pallas as pl
 
 from repro.core.da import DAConfig, bit_coefs
 
+#: Lane width of a TPU vreg: the input block's last dim is a multiple of it.
+_LANES = 128
 
-def _da_vmm_kernel(x_ref, lut_ref, out_ref, *, cfg: DAConfig, bg: int):
+
+def _da_vmm_kernel(x_ref, e_ref, lut_ref, out_ref, *, cfg: DAConfig):
     """One (m, n, g) tile: bg PMA groups × all bit-planes, accumulated."""
-    l = cfg.group_size
-    r = 1 << l
+    r = 1 << cfg.group_size
     g_idx = pl.program_id(2)
 
     x = x_ref[...]  # [bm, bg*L] int32 codes of this group chunk
+    expand = e_ref[...]  # [bg*L, bg*R] 0 / 2^i address expansion
     lut = lut_ref[...].astype(jnp.float32)  # [bg*R, bn]
-    bm = x.shape[0]
 
-    mask = (1 << cfg.x_bits) - 1
-    xm = jnp.bitwise_and(x, mask).reshape(bm, bg, l)
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, l), 2)
-
-    # one-hot column index decomposition: col c ↔ (group c//R, address c%R)
-    col_addr = jax.lax.broadcasted_iota(jnp.int32, (1, bg, r), 2)
+    xm = jnp.bitwise_and(x, (1 << cfg.x_bits) - 1)
+    # column c of a group chunk is wordline (c mod R) of PMA group c // R
+    col_addr = jnp.bitwise_and(
+        jax.lax.broadcasted_iota(jnp.int32, (1, expand.shape[1]), 1), r - 1)
 
     coefs = bit_coefs(cfg.x_bits, cfg.x_signed)
     acc = jnp.zeros(out_ref.shape, dtype=jnp.int32)
-    for b in range(cfg.x_bits):  # the 8 bit-serial "memory cycles", unrolled
-        bits = jnp.bitwise_and(jnp.right_shift(xm, b), 1)
-        addr = jnp.sum(bits << shifts, axis=-1)  # [bm, bg] PMA addresses
-        onehot = (addr[:, :, None] == col_addr).astype(jnp.float32)
-        onehot = onehot.reshape(bm, bg * r)  # decoder output (wordlines)
-        mr = jnp.dot(onehot, lut, preferred_element_type=jnp.float32)
+    for b in range(cfg.x_bits):  # the bit-serial "memory cycles", unrolled
+        bits = jnp.bitwise_and(jnp.right_shift(xm, b), 1).astype(expand.dtype)
+        addr = jnp.dot(bits, expand, preferred_element_type=jnp.float32)
+        onehot = (addr.astype(jnp.int32) == col_addr).astype(jnp.float32)
+        mr = jnp.dot(onehot, lut, preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
         acc = acc + jnp.int32(coefs[b]) * mr.astype(jnp.int32)
 
     @pl.when(g_idx == 0)
@@ -66,6 +74,24 @@ def _da_vmm_kernel(x_ref, lut_ref, out_ref, *, cfg: DAConfig, bg: int):
         out_ref[...] += acc
 
 
+def _address_expansion(bg: int, l: int) -> jax.Array:
+    """[bg·L, bg·2^L] bfloat16: row ``gi·L + i`` holds 2^i on the 2^L
+    columns of group gi, so ``bits @ E`` repeats each group's address over
+    its wordlines."""
+    r = 1 << l
+    rows = jnp.arange(bg * l)[:, None]
+    cols = jnp.arange(bg * r)[None, :]
+    return jnp.where(rows // l == cols // r, 2 ** (rows % l), 0).astype(
+        jnp.bfloat16)
+
+
+def _default_group_block(num_groups_: int, group_size: int) -> int:
+    """Groups per LUT block: the fewest whose inputs fill whole vreg lane
+    rows (``bg·L`` a multiple of 128), or all groups when K is smaller."""
+    bg = math.lcm(group_size, _LANES) // group_size
+    return min(bg, num_groups_)
+
+
 @functools.partial(
     jax.jit, static_argnames=("cfg", "bm", "bn", "bg", "interpret")
 )
@@ -74,14 +100,15 @@ def da_vmm_pallas(
     luts: jax.Array,
     cfg: DAConfig,
     bm: int = 256,
-    bn: int = 256,
-    bg: int = 8,
+    bn: int = 128,
+    bg: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """DA VMM via Pallas. xq [M, K] int32 codes; luts [G, 2^L, N] int32.
 
-    Returns int32 [M, N] == xq @ W exactly. ``interpret=None`` derives the
-    execution mode from the platform: compiled on TPU, interpret elsewhere.
+    Returns int32 [M, N] == xq @ W exactly. ``bg=None`` picks
+    :func:`_default_group_block`. ``interpret=None`` derives the execution
+    mode from the platform: compiled on TPU, interpret elsewhere.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -97,7 +124,7 @@ def da_vmm_pallas(
         xq = jnp.pad(xq, ((0, 0), (0, pad_k)))
     bm = min(bm, m)
     bn = min(bn, n)
-    bg = min(bg, g)
+    bg = _default_group_block(g, l) if bg is None else min(bg, g)
     pm, pn, pg = (-m) % bm, (-n) % bn, (-g) % bg
     if pm:
         xq = jnp.pad(xq, ((0, pm), (0, 0)))
@@ -110,14 +137,16 @@ def da_vmm_pallas(
     lut2d = luts.reshape(gg * r, nn)
 
     out = pl.pallas_call(
-        functools.partial(_da_vmm_kernel, cfg=cfg, bg=bg),
+        functools.partial(_da_vmm_kernel, cfg=cfg),
         grid=(mm // bm, nn // bn, gg // bg),
         in_specs=[
             pl.BlockSpec((bm, bg * l), lambda i, j, gi: (i, gi)),
+            # constant block index: fetched once, resident across the grid
+            pl.BlockSpec((bg * l, bg * r), lambda i, j, gi: (0, 0)),
             pl.BlockSpec((bg * r, bn), lambda i, j, gi: (gi, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, gi: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), jnp.int32),
         interpret=interpret,
-    )(xq, lut2d)
+    )(xq.astype(jnp.int32), _address_expansion(bg, l), lut2d)
     return out[:m, :n]

@@ -32,7 +32,7 @@ def da_vmm(
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "reference"
     if backend == "pallas":
-        return da_vmm_pallas(xq, luts, cfg, interpret=not _on_tpu(), **tiles)
+        return da_vmm_pallas(xq, luts, cfg, **tiles)
     return ref.da_vmm_ref(xq, luts, cfg)
 
 
@@ -47,5 +47,5 @@ def bitplane_vmm(
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "reference"
     if backend == "pallas":
-        return bitplane_vmm_pallas(xq, wq, cfg, interpret=not _on_tpu(), **tiles)
+        return bitplane_vmm_pallas(xq, wq, cfg, **tiles)
     return ref.bitplane_vmm_ref(xq, wq, cfg)

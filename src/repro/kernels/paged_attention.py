@@ -1,43 +1,41 @@
-"""Fused Pallas paged-attention decode kernel: in-kernel page-table walk.
+"""Fused Pallas paged-attention read: in-kernel page-table walk.
 
 The serving gather path (``models/attention.paged_gather_read``) re-creates
 the full ``[B, W*ps, kv, hd]`` KV view from the batch-free page pool every
-decode step — an HBM gather whose traffic dwarfs the attention math at
-decode shapes. This kernel walks the page table ON-CHIP instead: the grid is
+step — an HBM gather whose traffic dwarfs the attention math at decode
+shapes. This kernel walks the page table ON-CHIP instead: the grid is
 (batch row, page slot) and the K/V BlockSpec index maps read the
 scalar-prefetched page table, so each grid step DMAs exactly ONE physical
 page into VMEM. Pages the table does not name are never touched, and the
 dense ``[B, S, kv, hd]`` view never exists in HBM.
 
-Per page the kernel computes that page's grouped-GQA score block (q stays in
-its ``[kv, G]`` grouped layout; repeated KV heads are never materialized)
-and folds it into a running row-max — the online-softmax accumulation
-across the page walk. Masked scores and the page's V rows are staged in
-VMEM scratch, which Pallas persists across the sequential grid. The final
-page's step runs the fused epilogue: exp/normalize against the accumulated
-max, probs cast, PV contraction — one kernel, no HBM round-trip for scores.
+Each step stages its page's K/V rows (dequantized in-register for int8 /
+int4 pools) at their logical offset in a VMEM row buffer, which Pallas
+persists across the sequential grid. The row's last page runs the epilogue:
+per KV head, one ``[T·G, hd] x [S, hd]`` score matmul for the head's group of
+query heads, the ragged mask, softmax, and the PV matmul. Both matmuls
+accumulate in float32 (the MXU's accumulator).
 
-Ragged masking happens in-kernel: key position ``w*ps + i`` contributes to
-query ``t`` iff ``kpos <= tpos[b, t]``. Pad lanes point at the garbage page
+Ragged masking happens in-kernel: key position ``s`` contributes to query
+``t`` iff ``s <= tpos[b, t]``. Pad lanes point at the garbage page
 (physical page 0) with ``tpos`` beyond every real position, so garbage rows
 are masked out exactly as in the gather path.
 
-Numerics match the gather path BIT-FOR-BIT at the default
-``softmax_dtype="float32"`` (CI asserts it, the same way the paged==dense
-tests do): each page's score block is a slice of the same einsum the gather
-path runs, the running max equals the global masked max exactly (max is
-order-independent), and the epilogue replicates ``jax.nn.softmax``'s
-``exp(x - max) / sum`` form with the same dtypes and casts. Deferring
-exp/normalize to the epilogue — rather than rescaling a running sum at
-every page like a classic flash-decode kernel — is what keeps the
-roundings identical; the rescale chain would round differently at each page
-boundary. The cost is VMEM scratch linear in the table width, which at
-serving page counts is far below the VMEM budget. For sub-f32 softmax
-dtypes (``softmax_dtype="bfloat16"``) exact bit-parity across lowerings is
-not attainable in principle — XLA fuses ``exp``+``reduce`` and keeps f32
-intermediates across the pair, eliding bf16 roundings an op-by-op kernel
-must perform — so there the kernel is within one bf16 ulp per reduction,
-not bitwise.
+Numerics match the gather path BIT-FOR-BIT in interpret mode at the default
+``softmax_dtype="float32"`` (CI asserts it): every score is the same
+float32-accumulated dot over ``hd`` the gather einsum computes, the
+epilogue reduces over the same ``S`` keys in the same order, and it
+replicates ``jax.nn.softmax``'s ``exp(x - max) / sum`` form with the same
+dtypes and casts. For sub-f32 softmax dtypes (``softmax_dtype="bfloat16"``)
+exact bit-parity across lowerings is not attainable in principle — XLA fuses
+``exp``+``reduce`` and keeps f32 intermediates across the pair, eliding bf16
+roundings an op-by-op kernel must perform — so there the kernel is within
+one bf16 ulp per reduction, not bitwise. Compiled on a TPU the two reads
+are two lowerings that may sum in different orders, so bit-parity there is
+not guaranteed; ``chip_smoke.py`` holds them to a stated tolerance.
+
+VMEM holds the staged K and V of one row: ``2 · W·ps · kv · hd`` elements,
+linear in the table width.
 
 ``interpret=None`` derives the execution mode from the backend platform:
 compiled on TPU, interpreter everywhere else (the CPU CI correctness path).
@@ -53,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.models import kv_quant as _kvq
+from repro.models.attention import group_queries, ungroup_queries
 
 # Matches models/attention.NEG_INF so masked lanes are bit-identical.
 NEG_INF = -1e30
@@ -65,84 +64,62 @@ def _default_interpret() -> bool:
 
 def _paged_attn_kernel(
     table_ref,  # scalar-prefetch: [B, W] page table (SMEM)
-    q_ref,      # [1, T, H, hd] query block for this batch row
-    tpos_ref,   # [1, T] temporal positions for this batch row
+    q_ref,      # [1, kv, T*G, hd] this row's queries, grouped by KV head
+    tpos_ref,   # [1, T*G, 1] temporal position of each query row
     k_ref,      # [1, ps, kv, hd(/2)] — ONE physical K page, chosen by table
     v_ref,      # [1, ps, kv, hd(/2)] — ONE physical V page
-    *rest,      # [ks_ref, vs_ref,] o_ref, s_scr, v_scr, m_scr — the scale
-    #             pages [1, ps, kv, 1] ride the same table-indexed walk and
-    #             are present iff the pool is quantized (kv_fmt != "fp")
+    *rest,      # [ks_ref, vs_ref,] o_ref, k_scr, v_scr — the scale pages
+    #             [1, ps, kv, 1] ride the same table-indexed walk and are
+    #             present iff the pool is quantized (kv_fmt != "fp")
     n_pages_walked: int,
     page_size: int,
-    n_kv: int,
-    n_groups: int,
     softmax_dtype,
     mask_mode: str,
     kv_fmt: str,
 ):
     del table_ref  # consumed by the BlockSpec index maps
     if kv_fmt == "fp":
-        o_ref, s_scr, v_scr, m_scr = rest
+        o_ref, k_scr, v_scr = rest
     else:
-        ks_ref, vs_ref, o_ref, s_scr, v_scr, m_scr = rest
+        ks_ref, vs_ref, o_ref, k_scr, v_scr = rest
     wi = pl.program_id(1)
     ps = page_size
-    t = q_ref.shape[1]
-    hd = q_ref.shape[3]
-    sd = softmax_dtype
 
-    # Dequantize the DMA'd page in-register: the same elementwise formula
-    # the gather read applies to its gathered view (kv_quant.dequantize_kv),
-    # so each element is bitwise the gather path's — the per-page score
-    # block below stays a slice of the gather einsum, quantized or not.
+    # Stage this page at its logical offset, dequantized in-register with the
+    # gather read's exact elementwise formula (kv_quant.dequantize_kv).
     if kv_fmt == "fp":
-        k_page = k_ref[...]
-        v_page = v_ref[0]
+        k_page, v_page = k_ref[0], v_ref[0]
     else:
-        k_page = _kvq.dequantize_kv(k_ref[...], ks_ref[...], kv_fmt,
-                                    q_ref.dtype)
+        k_page = _kvq.dequantize_kv(k_ref[0], ks_ref[0], kv_fmt, q_ref.dtype)
         v_page = _kvq.dequantize_kv(v_ref[0], vs_ref[0], kv_fmt, q_ref.dtype)
-
-    # Stage this page's V rows at their logical offset in the sequence.
+    k_scr[pl.ds(wi * ps, ps)] = k_page
     v_scr[pl.ds(wi * ps, ps)] = v_page
-
-    # Grouped-GQA scores for this page: slice of the gather path's einsum
-    # over the same contraction (hd), so it is bitwise the same block.
-    qg = q_ref[0].reshape(t, n_kv, n_groups, hd)[None]
-    scores = jnp.einsum("btkgd,bskd->bkgts", qg, k_page) / (hd ** 0.5)
-    scores = scores.astype(sd)
-
-    # Ragged/garbage masking: key position valid iff kpos <= tpos.
-    kpos = wi * ps + jax.lax.broadcasted_iota(jnp.int32, (t, ps), 1)
-    valid = (kpos <= tpos_ref[0][:, None])[None, None, None]
-    neg = jnp.asarray(NEG_INF, sd)
-    if mask_mode == "additive":
-        scores = scores + jnp.where(valid, jnp.asarray(0.0, sd), neg)
-    else:
-        scores = jnp.where(valid, scores, neg)
-    s_scr[:, :, :, pl.ds(wi * ps, ps)] = scores[0]
-
-    # Online accumulation: running max over pages == global max, exactly.
-    page_max = jnp.max(scores[0], axis=-1)
-
-    @pl.when(wi == 0)
-    def _init():
-        m_scr[...] = page_max
-
-    @pl.when(wi > 0)
-    def _fold():
-        m_scr[...] = jnp.maximum(m_scr[...], page_max)
 
     @pl.when(wi == n_pages_walked - 1)
     def _epilogue():
-        # Mirror jax.nn.softmax(scores, axis=-1) bit-for-bit:
-        # exp(x - max) / sum, in softmax_dtype, then cast to q dtype.
-        s_all = s_scr[...]
-        unnorm = jnp.exp(s_all - m_scr[...][..., None])
-        probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
-        probs = probs.astype(q_ref.dtype)[None]
-        out = jnp.einsum("bkgts,bskd->btkgd", probs, v_scr[...][None])
-        o_ref[...] = out.reshape(1, t, n_kv * n_groups, hd).astype(o_ref.dtype)
+        n_kv, tg, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+        s = k_scr.shape[0]
+        sd = softmax_dtype
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (tg, s), 1)
+        valid = kpos <= tpos_ref[0]
+        for j in range(n_kv):
+            qj = q_ref[0, j]
+            scores = jax.lax.dot_general(
+                qj, k_scr[:, j, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / (hd ** 0.5)
+            scores = scores.astype(sd)
+            neg = jnp.asarray(NEG_INF, sd)
+            if mask_mode == "additive":
+                scores = scores + jnp.where(valid, jnp.asarray(0.0, sd), neg)
+            else:
+                scores = jnp.where(valid, scores, neg)
+            # jax.nn.softmax's form: exp(x - max) / sum, in softmax_dtype
+            unnorm = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+            probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
+            out = jax.lax.dot_general(
+                probs.astype(qj.dtype), v_scr[:, j, :],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            o_ref[0, j] = out.astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -162,74 +139,77 @@ def paged_attention(
 
     Drop-in replacement for the gather read over an already-written pool
     (scatter of the current step's K/V happens before either read). The
-    page walk, ragged masking, online-softmax accumulation and PV
-    contraction all run inside one Pallas kernel; see the module docstring
-    for the bit-parity argument.
+    page walk, ragged masking, softmax and PV contraction all run inside
+    one Pallas kernel; see the module docstring for the bit-parity argument.
 
     Quantized pools (int8 codes, or int4 nibble pairs packed along hd) pass
     their in-page scales: the scale blocks ride the SAME scalar-prefetch
     index map as the page walk — each grid step DMAs one codes page plus
     its ``[ps, kv, 1]`` scale sliver — and dequantization happens
-    in-register before the score einsum, with the gather backend's exact
-    elementwise formula, so the two backends stay bit-identical on
-    quantized pages too.
+    in-register with the gather backend's exact elementwise formula.
     """
     b, t, h, hd = q.shape
     _, ps, kv, hd_p = k_pool.shape
     w = page_table.shape[1]
-    s = w * ps
     if h % kv:
         raise ValueError(f"n_heads={h} not divisible by n_kv_heads={kv}")
     g = h // kv
     if interpret is None:
         interpret = _default_interpret()
-    sd = jnp.dtype(softmax_dtype)
     kv_fmt = _kvq.kv_format(k_pool, k_scale, hd)
+
+    # Group the queries by KV head outside the kernel (the gather read's
+    # layout), so every in-kernel matmul is a plain 2-D MXU pass per head;
+    # each query row carries its own temporal position for the ragged mask.
+    qg = group_queries(q, kv)
+    tpos_rows = jnp.repeat(tpos.astype(jnp.int32), g, axis=1)[..., None]
 
     kernel = functools.partial(
         _paged_attn_kernel,
         n_pages_walked=w,
         page_size=ps,
-        n_kv=kv,
-        n_groups=g,
-        softmax_dtype=sd,
+        softmax_dtype=jnp.dtype(softmax_dtype),
         mask_mode=mask_mode,
         kv_fmt=kv_fmt,
     )
+    row_spec = pl.BlockSpec((1, kv, t * g, hd),
+                            lambda bi, wi, tbl: (bi, 0, 0, 0))
     page_spec = pl.BlockSpec((1, ps, kv, hd_p),
                              lambda bi, wi, tbl: (tbl[bi, wi], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, t, h, hd), lambda bi, wi, tbl: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, t), lambda bi, wi, tbl: (bi, 0)),
+        row_spec,
+        pl.BlockSpec((1, t * g, 1), lambda bi, wi, tbl: (bi, 0, 0)),
         # The page walk: block index = table entry for (row, slot).
         page_spec,
         page_spec,
     ]
-    operands = [q, tpos.astype(jnp.int32), k_pool, v_pool]
+    operands = [qg, tpos_rows, k_pool, v_pool]
     if kv_fmt != "fp":
         scale_spec = pl.BlockSpec((1, ps, kv, 1),
                                   lambda bi, wi, tbl: (tbl[bi, wi], 0, 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+        # the TPU kernel compiler loads no float16; widening is exact, and
+        # the scale pools are hd× smaller than the code pools
+        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    # staged rows are dequantized, so quantized pools stage at q's dtype
+    stage_dtype = k_pool.dtype if kv_fmt == "fp" else q.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, w),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, t, h, hd), lambda bi, wi, tbl: (bi, 0, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((kv, g, t, s), sd),
-            # staged V rows are dequantized, so the scratch holds q dtype
-            pltpu.VMEM((s, kv, hd),
-                       v_pool.dtype if kv_fmt == "fp" else q.dtype),
-            pltpu.VMEM((kv, g, t), sd),
+            pltpu.VMEM((w * ps, kv, hd), stage_dtype),
+            pltpu.VMEM((w * ps, kv, hd), stage_dtype),
         ],
     )
     # named_scope: the kernel shows up as one attributable op in profiler
     # captures (kv format in the name separates fp/int8/int4 dispatches)
     with jax.named_scope(f"paged_attn_fused_{kv_fmt}"):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, t, h, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, kv, t * g, hd), q.dtype),
             interpret=interpret,
         )(page_table.astype(jnp.int32), *operands)
+    return ungroup_queries(out, t)

@@ -6,29 +6,9 @@ from __future__ import annotations
 import jax
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """jax.shard_map across jax versions: 0.4.x keeps it in experimental, and
-    the check flag was renamed check_rep → check_vma after the promotion, so
-    sniff the actual signature rather than keying on namespace presence."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check})
-
-
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # so on older jax the plain call is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -94,6 +94,10 @@ def main():
     if args.save_artifact and args.quant == "none":
         raise SystemExit("--save-artifact requires a DA --quant mode")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import dataclasses
 
     import jax
@@ -180,7 +184,7 @@ def main():
     dt = time.perf_counter() - t0
     toks = sum(len(r.generated) for r in done.values())
     print(f"served {len(done)} requests / {toks} tokens in {dt:.1f}s "
-          f"({toks/dt:.1f} tok/s)")
+          f"({toks/dt:.1f} tok/s on {jax.devices()[0].platform})")
     sm = eng.metrics().get("spec")
     if sm:
         print(f"spec[{sm['provider']}] gamma={sm['gamma']} "

@@ -163,20 +163,48 @@ def _repeat_kv(k, n_heads: int):
     return jnp.repeat(k, n_heads // kv, axis=2)
 
 
-def _gqa_scores(q, k):
-    """q: [B,T,H,hd], k: [B,S,Kv,hd] → scores [B,Kv,G,T,S] (H = Kv·G)."""
+def group_queries(q, n_kv: int):
+    """[B,T,H,hd] → [B,Kv,T·G,hd]: each KV head's query rows, ordered
+    (t, g) — the layout both paged-attention reads contract per head."""
     b, t, h, hd = q.shape
+    g = h // n_kv
+    return q.reshape(b, t, n_kv, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, n_kv, t * g, hd)
+
+
+def ungroup_queries(o, t: int):
+    """Inverse of :func:`group_queries`: [B,Kv,T·G,hd] → [B,T,H,hd]."""
+    b, kv, tg, hd = o.shape
+    g = tg // t
+    return o.reshape(b, kv, t, g, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, t, kv * g, hd)
+
+
+def _gqa_scores(q, k):
+    """q: [B,T,H,hd], k: [B,S,Kv,hd] → float32 scores [B,Kv,T·G,S]
+    (H = Kv·G, rows as in :func:`group_queries`), accumulated in float32.
+
+    Keys are contracted head-major (``[B,Kv,S,hd]``), the per-head
+    ``[T·G, hd] x [S, hd]`` form the fused kernel runs, so on one backend
+    the two reads compute each score identically."""
+    hd = q.shape[-1]
     kv = k.shape[2]
-    g = h // kv
-    qg = q.reshape(b, t, kv, g, hd)
-    return jnp.einsum("btkgd,bskd->bkgts", qg, k) / (hd ** 0.5)
+    return jnp.einsum("bkqd,bksd->bkqs", group_queries(q, kv),
+                      k.transpose(0, 2, 1, 3),
+                      preferred_element_type=jnp.float32) / (hd ** 0.5)
 
 
-def _gqa_out(probs, v):
-    """probs: [B,Kv,G,T,S], v: [B,S,Kv,hd] → [B,T,H,hd]."""
-    b, kv, g, t, s = probs.shape
-    out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
-    return out.reshape(b, t, kv * g, v.shape[-1])
+def _gqa_out(probs, v, t: int):
+    """probs: [B,Kv,T·G,S], v: [B,S,Kv,hd] → [B,T,H,hd] in probs' dtype,
+    accumulated in float32."""
+    out = jnp.einsum("bkqs,bksd->bkqd", probs, v.transpose(0, 2, 1, 3),
+                     preferred_element_type=jnp.float32).astype(probs.dtype)
+    return ungroup_queries(out, t)
+
+
+def _query_row_mask(mask, n_groups: int):
+    """[B,T,S] key-validity mask → [B,1,T·G,S], broadcast over KV heads."""
+    return jnp.repeat(mask, n_groups, axis=1)[:, None]
 
 
 def _masked_softmax(scores, mask, softmax_dtype, mask_mode: str):
@@ -203,9 +231,11 @@ def _apply_mask_softmax(scores, mask, cfg: ModelConfig):
 
 def _decode_attention(q, k, v, mask, cfg: ModelConfig):
     """Grouped GQA attention over the cache (decode: T small)."""
+    g = q.shape[2] // k.shape[2]
     scores = _gqa_scores(q, k)
-    probs = _apply_mask_softmax(scores, mask[:, None, None], cfg).astype(q.dtype)
-    return _gqa_out(probs, v)
+    probs = _apply_mask_softmax(scores, _query_row_mask(mask, g),
+                                cfg).astype(q.dtype)
+    return _gqa_out(probs, v, q.shape[1])
 
 
 def _naive_attention(q, k, v, mask, cfg: ModelConfig):
@@ -325,10 +355,11 @@ def paged_gather_read(q, k_pool, v_pool, page_table, tpos, *,
     vg = constrain(vg, ("batch", "kv_seq", "kv_heads", "head_dim"))
     kpos = jnp.arange(kg.shape[1])
     mask = kpos[None, None, :] <= tpos[:, :, None]    # [B, T, S] causal+length
+    g = q.shape[2] // kg.shape[2]
     scores = _gqa_scores(q, kg)
-    probs = _masked_softmax(scores, mask[:, None, None], softmax_dtype,
+    probs = _masked_softmax(scores, _query_row_mask(mask, g), softmax_dtype,
                             mask_mode).astype(q.dtype)
-    return _gqa_out(probs, vg)
+    return _gqa_out(probs, vg, q.shape[1])
 
 
 def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,

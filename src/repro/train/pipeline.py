@@ -93,14 +93,12 @@ def make_pipelined_apply(
             return pipeline_forward(stage_fn, sp, xm, axis,
                                     n_stages=mesh.shape[axis])
 
-        from repro.launch.mesh import shard_map_compat
-
-        shard = shard_map_compat(
+        shard = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(axis), P()),
             out_specs=P(),
-            check=False,
+            check_vma=False,
         )
         y_micro = shard(stage_params, x_micro)
         return y_micro.reshape((b,) + y_micro.shape[2:])

@@ -147,11 +147,10 @@ def test_compressed_allreduce_dp():
             mean, new_err = allreduce_compressed({"w": g_local}, err, "data")
             return mean["w"], new_err
 
-        from repro.launch.mesh import shard_map_compat
-        f_sh = shard_map_compat(f, mesh=mesh,
-                                in_specs=(P("data"), {"w": P()}),
-                                out_specs=(P(), {"w": P()}),
-                                check=False)
+        f_sh = jax.shard_map(f, mesh=mesh,
+                             in_specs=(P("data"), {"w": P()}),
+                             out_specs=(P(), {"w": P()}),
+                             check_vma=False)
         err0 = init_error({"w": jnp.zeros((64,))})
         mean, err = f_sh(g, err0)
         true_mean = jnp.mean(g, axis=0)

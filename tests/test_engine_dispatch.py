@@ -3,6 +3,7 @@ resolves to a registered, eligible backend; a missing autotune cache degrades
 to the deterministic heuristic; unknown modes fail loudly.
 """
 import json
+import warnings
 
 import jax.numpy as jnp
 import numpy as np
@@ -262,7 +263,14 @@ def test_cost_table_rejects_other_device(tmp_path):
     p.write_text(json.dumps(
         {"version": 1, "device": other, "table": {bucket: {"pallas_lut": 0.1}}}
     ))
-    assert load_cost_table(p) == {}
+    here = jax.default_backend()
+    with pytest.warns(UserWarning,
+                      match=f"tuned on '{other}' but this process runs on "
+                            f"'{here}'"):
+        assert load_cost_table(p) == {}
+    with warnings.catch_warnings():  # once per cache and device, not per load
+        warnings.simplefilter("error")
+        assert load_cost_table(p) == {}
 
 
 def test_explicit_mode_enforces_capabilities():
