@@ -18,14 +18,11 @@ from repro.obs.metrics import (
     METRICS_SCHEMA_VERSION,
     MetricsRegistry,
     TIME_BUCKETS,
-    default_registry,
 )
 from repro.obs.trace import (
     SCHED_TRACK,
     TraceEvent,
     TraceRecorder,
-    default_tracer,
-    device_span,
     request_track,
 )
 from repro.obs.export import (
@@ -85,9 +82,6 @@ __all__ = [
     "bitslice_design",
     "chrome_trace",
     "da_design",
-    "default_registry",
-    "default_tracer",
-    "device_span",
     "draft_price",
     "prometheus_text",
     "request_track",

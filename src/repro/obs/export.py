@@ -26,7 +26,7 @@ import json
 import math
 import os
 import re
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 from repro.obs.metrics import (
     Counter,
@@ -275,18 +275,6 @@ def validate_prometheus_text(text: str) -> List[str]:
     if not samples:
         errs.append("no samples found")
     return errs
-
-
-# ---------------------------------------------------------------------------
-# registry-schema helpers shared with benchmarks/stamp.py
-# ---------------------------------------------------------------------------
-
-
-def snapshot_with_schema(registry: Optional[MetricsRegistry]) -> Dict[str, Any]:
-    """Registry snapshot in the BENCH_*.json schema (version-stamped)."""
-    if registry is None:
-        return {"metrics_schema_version": METRICS_SCHEMA_VERSION}
-    return registry.snapshot()
 
 
 # ---------------------------------------------------------------------------

@@ -265,14 +265,3 @@ def _fmt_labels(key: LabelKey) -> str:
         return ""
     return "{" + ",".join(f"{k}={v}" for k, v in key) + "}"
 
-
-# -- module default ----------------------------------------------------------
-# One process-wide registry for code without an engine in hand (kernel-level
-# counters, ad-hoc scripts).  Engines build their OWN registry so parallel
-# engines in one process (e.g. the spec-decode benchmark's paired runs) never
-# share series.
-_default = MetricsRegistry()
-
-
-def default_registry() -> MetricsRegistry:
-    return _default

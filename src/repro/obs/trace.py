@@ -20,10 +20,12 @@ memory on long serves: the newest ``capacity`` events win, and
 :meth:`span_balance` is computed from lifetime depth counters, not the
 buffer, so balance checks survive wraparound.
 
-``device_span`` bridges host spans to device profiles: inside it, a
-``jax.profiler.TraceAnnotation`` (host) plus ``jax.named_scope`` (trace-time
-HLO metadata) make the XLA profiler's device timeline line up with the
-host-side request spans when both are captured.
+:meth:`TraceRecorder.phase` is the one way to open a span that a profiler
+capture should see: it records the span twice over the same interval, as a
+``jax.profiler.TraceAnnotation`` on the profiler's clock (so the device
+timeline lines up with what the host was doing) and as an ``"X"`` event on
+the recorder's.  The scheduler opens one per host phase of its tick
+(``sched.*``) and one per device dispatch (``paged_step[RxT]``, …).
 """
 from __future__ import annotations
 
@@ -120,6 +122,15 @@ class TraceRecorder:
         finally:
             self.end(name, track)
 
+    def phase(self, name: str, track: str = SCHED_TRACK, **args):
+        """A span on both clocks: ``jax.profiler.TraceAnnotation(name)``
+        for a profiler capture and an ``"X"`` event on ``track``, bracketing
+        the same interval.  Disabled, it returns one shared null context (one
+        attribute test, nothing recorded, no annotation entered)."""
+        if not self.enabled:
+            return _OFF
+        return _Phase(self, name, track, args)
+
     def _now(self, ts: Optional[float]) -> float:
         return time.perf_counter() if ts is None else ts
 
@@ -138,26 +149,40 @@ class TraceRecorder:
         return len(self.events)
 
 
-@contextlib.contextmanager
-def device_span(name: str, enabled: bool = True) -> Iterator[None]:
-    """Host→device profiling bridge around a device dispatch.
+class _Phase:
+    """One open phase: a TraceAnnotation and an "X" event over the same
+    interval.  Entering yields the args dict, so a caller may add args it
+    only learns inside the span."""
 
-    Wraps the body in ``jax.profiler.TraceAnnotation`` so an XLA profiler
-    capture shows this host span on its timeline, aligned with the device
-    ops it dispatched.  No-op (one branch) when disabled.
-    """
-    if not enabled:
-        yield
-        return
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    __slots__ = ("_rec", "_name", "_track", "_args", "_ann", "_t0")
+
+    def __init__(self, rec: "TraceRecorder", name: str, track: str,
+                 args: Dict[str, Any]):
+        self._rec, self._name, self._track, self._args = rec, name, track, args
+
+    def __enter__(self) -> Dict[str, Any]:
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self._args
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._rec.complete(self._name, self._track, self._t0, t1 - self._t0,
+                           **self._args)
+        return False
 
 
-# -- module default ----------------------------------------------------------
-# Disabled by default: tracing is opt-in per engine (ServeEngine(trace=True)
-# or --trace-out) and costs one attribute test per call site when off.
-_default = TraceRecorder(enabled=False)
+class _ArgSink:
+    """What a disabled phase yields: takes args and keeps none."""
+
+    __slots__ = ()
+
+    def update(self, **args) -> None:
+        pass
 
 
-def default_tracer() -> TraceRecorder:
-    return _default
+#: the context every phase of a disabled recorder shares: no object is
+#: built per call, and nothing reaches the profiler
+_OFF = contextlib.nullcontext(_ArgSink())

@@ -58,7 +58,7 @@ from repro.models.model import forward
 from repro.obs import Observability
 from repro.obs.hwcost import HardwareCostModel, draft_price
 from repro.obs.metrics import ENERGY_BUCKETS
-from repro.obs.trace import SCHED_TRACK, device_span, request_track
+from repro.obs.trace import SCHED_TRACK, request_track
 from repro.serve.kvcache import (
     GARBAGE_PAGE,
     PagePool,
@@ -338,6 +338,12 @@ class PagedScheduler:
             "req_itl_seconds", "inter-token latency")
         self._h_tick = reg.histogram(
             "sched_tick_seconds", "wall time of one scheduler tick")
+        self._h_queue = reg.histogram(
+            "req_queue_wait_seconds", "submit to admission")
+        self._c_calls = reg.counter(
+            "sched_device_calls", "device calls issued, by phase")
+        self._c_d2h = reg.counter(
+            "sched_d2h_bytes", "bytes copied device-to-host, by phase")
         self._c_draft_steps = reg.counter(
             "spec_draft_steps", "draft-model steps issued")
         self._c_verify_steps = reg.counter(
@@ -614,13 +620,15 @@ class PagedScheduler:
             lane = _Lane(req=req, pages=pages, ctx=ctx, pos=hit,
                          admitted_t=time.perf_counter())
             self.lanes[i] = lane
+            wait = lane.admitted_t - req.submit_t
+            self._h_queue.observe(wait)
             if self._tr.enabled:
                 # one "running" span per residency period: begun here, ended
                 # by _preempt or the finish paths — span balance over a
                 # drained run is a tested invariant
                 self._tr.begin("running", request_track(req.uid),
                                ts=lane.admitted_t, lane=i, ctx_tokens=len(ctx),
-                               prefix_hit_tokens=hit)
+                               prefix_hit_tokens=hit, queue_wait_s=wait)
 
     # -- preemption / eviction -----------------------------------------------
     def _preempt(self, i: int) -> None:
@@ -729,8 +737,20 @@ class PagedScheduler:
     def step(self) -> int:
         """One scheduler tick: admit, run a compact chunked-prefill sub-batch
         (if any lane is still ingesting its prompt), then one pure decode
-        step over the full batch. Returns the number of active lanes."""
-        self._admit()
+        step over the full batch. Returns the number of active lanes.
+
+        With tracing on, the tick is the ``sched.tick`` span and each host
+        phase inside it a ``sched.*`` span (``TraceRecorder.phase``): admit,
+        then per device call pack → dispatch → wait → d2h → sample, then
+        retire."""
+        if not self.queue and all(l is None for l in self.lanes):
+            return 0
+        with self._tr.phase("sched.tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
+        with self._tr.phase("sched.admit"):
+            self._admit()
         active = [(i, l) for i, l in enumerate(self.lanes) if l is not None]
         if not active:
             return 0
@@ -755,40 +775,43 @@ class PagedScheduler:
             if plain:
                 progressed |= self._decode_phase(plain)
 
-        active = [(i, l) for i, l in enumerate(self.lanes) if l is not None]
-        if active and not progressed:
-            # pool jammed: keep only the oldest lane (guaranteed servable by
-            # the submit-time capacity check), requeue the rest
-            oldest = min(active, key=lambda t: t[1].admitted_t)[0]
-            for i, _ in active:
-                if i != oldest:
-                    self._preempt(i)
-        for i, l in ((i, l) for i, l in enumerate(self.lanes)
-                     if l is not None):
-            if i in progressed:
-                l.stalled_steps = 0
-            else:
-                l.stalled_steps += 1
-                if l.stalled_steps > self.stall_patience:
-                    self._preempt(i)  # stalled: hand its pages to the rest
-        live = sum(l is not None for l in self.lanes)
-        now = time.perf_counter()
-        self._h_tick.observe(now - t_tick)
-        self._g_lanes.set(live)
-        self._g_queue.set(len(self.queue))
-        self._g_used_pages.set(self.pool.used_pages)
-        if self._tr.enabled:
-            evict1 = (self.prefix.evictions if self.prefix is not None
-                      else 0)
-            self._tr.complete(
-                "tick", SCHED_TRACK, t_tick, now - t_tick,
-                lanes=live, decode_lanes=decode_count,
-                prefill_lanes=len(prefill), queue=len(self.queue),
-                pages_allocated=self.pool._allocs - allocs0,
-                cow_copies=int(self._c_cow.total - cow0),
-                prefix_evictions=evict1 - evict0,
-                used_pages=self.pool.used_pages)
-        return live
+        with self._tr.phase("sched.retire"):
+            active = [(i, l) for i, l in enumerate(self.lanes)
+                      if l is not None]
+            if active and not progressed:
+                # pool jammed: keep only the oldest lane (guaranteed
+                # servable by the submit-time capacity check), requeue the
+                # rest
+                oldest = min(active, key=lambda t: t[1].admitted_t)[0]
+                for i, _ in active:
+                    if i != oldest:
+                        self._preempt(i)
+            for i, l in ((i, l) for i, l in enumerate(self.lanes)
+                         if l is not None):
+                if i in progressed:
+                    l.stalled_steps = 0
+                else:
+                    l.stalled_steps += 1
+                    if l.stalled_steps > self.stall_patience:
+                        self._preempt(i)  # stalled: hand its pages to the rest
+            live = sum(l is not None for l in self.lanes)
+            now = time.perf_counter()
+            self._h_tick.observe(now - t_tick)
+            self._g_lanes.set(live)
+            self._g_queue.set(len(self.queue))
+            self._g_used_pages.set(self.pool.used_pages)
+            if self._tr.enabled:
+                evict1 = (self.prefix.evictions if self.prefix is not None
+                          else 0)
+                self._tr.complete(
+                    "tick", SCHED_TRACK, t_tick, now - t_tick,
+                    lanes=live, decode_lanes=decode_count,
+                    prefill_lanes=len(prefill), queue=len(self.queue),
+                    pages_allocated=self.pool._allocs - allocs0,
+                    cow_copies=int(self._c_cow.total - cow0),
+                    prefix_evictions=evict1 - evict0,
+                    used_pages=self.pool.used_pages)
+            return live
 
     def _submit_plan(self, phase: str, rows, poss) -> None:
         """analysis_debug gate: prove the page-aliasing invariants for one
@@ -819,32 +842,37 @@ class PagedScheduler:
         assert_plan_ok(plan)
         self.plans_checked += 1
 
-    def _run_batch(self, rows, plan, n_rows: int, t_step: int,
-                   phase: str = "step") -> np.ndarray:
-        """Issue one call of the unified step for ``rows`` = [(batch_row,
-        lane_idx, lane)]. Pad rows/columns carry the garbage position, so
-        their writes land in the garbage page and every real row's
-        ``kpos <= tpos`` mask excludes them."""
+    def _pack_step(self, rows, plan, n_rows: int, t_step: int,
+                   phase: str):
+        """The device arguments of one call of the unified step for ``rows``
+        = [(batch_row, lane_idx, lane)], each lane advancing ``plan[i]``
+        tokens from ``lane.pos``. Pad rows/columns carry the garbage
+        position, so their writes land in the garbage page and every real
+        row's ``kpos <= tpos`` mask excludes them."""
+        toks = {i: l.ctx[l.pos : l.pos + plan[i]] for _, i, l in rows}
+        poss = {i: range(l.pos, l.pos + plan[i]) for _, i, l in rows}
         if self.analysis_debug:
-            self._submit_plan(phase, rows, {
-                i: range(l.pos, l.pos + plan[i]) for _, i, l in rows})
-        tokens = np.zeros((n_rows, t_step), np.int32)
-        positions = np.full((n_rows, t_step), self.pad_pos, np.int32)
-        last_idx = np.zeros((n_rows,), np.int32)
-        table = np.full((n_rows, self.W), GARBAGE_PAGE, np.int32)
-        for r, i, l in rows:
-            n = plan[i]
-            tokens[r, :n] = l.ctx[l.pos : l.pos + n]
-            positions[r, :n] = np.arange(l.pos, l.pos + n)
-            last_idx[r] = n - 1
-            table[r, : len(l.pages)] = l.pages
-        with device_span(f"paged_step[{n_rows}x{t_step}]", self._tr.enabled):
-            logits, self.caches = self._step(
-                self.params, self.caches, jnp.asarray(tokens),
-                mk_positions(self.cfg, jnp.asarray(positions)),
-                jnp.asarray(table), jnp.asarray(last_idx),
-            )
-        return np.asarray(logits)
+            self._submit_plan(phase, rows, poss)
+        return self._pack_rows(rows, toks, poss, n_rows, t_step, self.cfg)
+
+    def _dispatch(self, name: str, phase: str, fn, *args):
+        """Issue one device call under its own span (``name`` carries the
+        call's [rows x T] shape, which pairs it with its executable in a
+        profiler capture)."""
+        self._c_calls.inc(phase=phase)
+        with self._tr.phase(name):
+            return fn(*args)
+
+    def _read_back(self, out, phase: str) -> np.ndarray:
+        """The call's synchronisation point, in two spans: ``sched.wait``
+        until the device has computed ``out``, then ``sched.d2h`` for the
+        copy to the host."""
+        with self._tr.phase("sched.wait"):
+            jax.block_until_ready(out)
+        with self._tr.phase("sched.d2h", bytes=out.nbytes):
+            host = np.asarray(out)
+        self._c_d2h.inc(host.nbytes, phase=phase)
+        return host
 
     def _hw_charge(self, req: Request, phase: str, n: int) -> float:
         """Price ``n`` executed token-passes of ``phase`` work on the DA
@@ -875,83 +903,100 @@ class PagedScheduler:
         budget = self.token_budget - decode_count
         if budget <= 0 and decode_count > 0:
             return set()  # decode saturates the budget this tick
-        sel = sorted(prefill, key=lambda t: t[1].admitted_t)
-        sel = sel[: self.prefill_lanes]
-        plan: Dict[int, int] = {}
-        for i, l in sel:
-            n = min(l.remaining, self.prefill_chunk, budget)
-            n = self._ensure_pages(l, n)  # may shrink or defer: backpressure
-            plan[i] = n
-            budget -= n
-        rows = [(r, i, l) for r, (i, l) in enumerate(
-            (i, l) for i, l in sel if plan[i] > 0)]
-        if not rows:
-            return set()
-        # cap at prefill_chunk so a non-pow2 chunk size uses the shape
-        # warmup() compiled, not a one-off pow2 round-up
-        t_step = min(pow2_bucket(max(plan[i] for _, i, _ in rows)),
-                     self.prefill_chunk)
-        t0 = time.perf_counter()
-        logits = self._run_batch(rows, plan, self.prefill_lanes, t_step,
-                                 phase="prefill")
+        with self._tr.phase("sched.pack", phase="prefill") as span:
+            sel = sorted(prefill, key=lambda t: t[1].admitted_t)
+            sel = sel[: self.prefill_lanes]
+            plan: Dict[int, int] = {}
+            for i, l in sel:
+                n = min(l.remaining, self.prefill_chunk, budget)
+                # may shrink or defer: backpressure
+                n = self._ensure_pages(l, n)
+                plan[i] = n
+                budget -= n
+            rows = [(r, i, l) for r, (i, l) in enumerate(
+                (i, l) for i, l in sel if plan[i] > 0)]
+            if not rows:
+                return set()
+            # cap at prefill_chunk so a non-pow2 chunk size uses the shape
+            # warmup() compiled, not a one-off pow2 round-up
+            t_step = min(pow2_bucket(max(plan[i] for _, i, _ in rows)),
+                         self.prefill_chunk)
+            span.update(rows=len(rows), t_step=t_step)
+            t0 = time.perf_counter()
+            args = self._pack_step(rows, plan, self.prefill_lanes, t_step,
+                                   "prefill")
+        logits, self.caches = self._dispatch(
+            f"paged_step[{self.prefill_lanes}x{t_step}]", "prefill",
+            self._step, self.params, self.caches, *args)
+        logits = self._read_back(logits, "prefill")
         now = time.perf_counter()
-        if self._tr.enabled:
+        with self._tr.phase("sched.sample", phase="prefill"):
+            if self._tr.enabled:
+                for r, i, l in rows:
+                    extra = ({"est_pj": self._hw_prices["prefill"][0]
+                              * plan[i]} if self.hw is not None else {})
+                    self._tr.complete(
+                        "prefill_chunk", request_track(l.req.uid), t0,
+                        now - t0, tokens=plan[i], pos=l.pos, **extra)
+                self._tr.complete("prefill", SCHED_TRACK, t0, now - t0,
+                                  lanes=len(rows), t_step=t_step)
             for r, i, l in rows:
-                extra = ({"est_pj": self._hw_prices["prefill"][0] * plan[i]}
-                         if self.hw is not None else {})
-                self._tr.complete("prefill_chunk", request_track(l.req.uid),
-                                  t0, now - t0, tokens=plan[i], pos=l.pos,
-                                  **extra)
-            self._tr.complete("prefill", SCHED_TRACK, t0, now - t0,
-                              lanes=len(rows), t_step=t_step)
-        for r, i, l in rows:
-            l.pos += plan[i]
-            self._hw_charge(l.req, "prefill", plan[i])
-            self._c_ctx.inc(plan[i])
-            self._maybe_cache_prefix(l)  # before _sample can free the pages
-            if l.remaining == 0:  # chunk covered the last unseen token
-                self._sample(i, l, logits[r], now)
+                l.pos += plan[i]
+                self._hw_charge(l.req, "prefill", plan[i])
+                self._c_ctx.inc(plan[i])
+                # before _sample can free the pages
+                self._maybe_cache_prefix(l)
+                if l.remaining == 0:  # chunk covered the last unseen token
+                    self._sample(i, l, logits[r], now)
         return {i for _, i, _ in rows}
 
     def _decode_phase(self, decode) -> set:
         """All decoding lanes advance one token in a [batch, 1] step; a lane
         that cannot get its next page preempts the youngest other lane."""
-        ready = set()
-        for i, l in sorted(decode, key=lambda t: t[1].admitted_t):
-            if self.lanes[i] is not l:
-                continue  # preempted as a victim earlier in this loop
-            got = self._ensure_pages(l, 1)
-            while got == 0:
-                victim = self._youngest_other(i)
-                if victim is None:
-                    break
-                self._preempt(victim)
+        with self._tr.phase("sched.pack", phase="decode") as span:
+            ready = set()
+            for i, l in sorted(decode, key=lambda t: t[1].admitted_t):
+                if self.lanes[i] is not l:
+                    continue  # preempted as a victim earlier in this loop
                 got = self._ensure_pages(l, 1)
-            if got:
-                ready.add(i)
-        live = [(i, l) for i, l in decode
-                if i in ready and self.lanes[i] is l]
-        if not live:
-            return set()
-        plan = {i: 1 for i, _ in live}
-        # lanes compact into a bucketed width (requests own pages, not
-        # batch rows, so a half-empty batch never pays full-width compute)
-        width = width_bucket(len(live), self.b)
-        rows = [(r, i, l) for r, (i, l) in enumerate(live)]
-        t0 = time.perf_counter()
-        logits = self._run_batch(rows, plan, width, 1, phase="decode")
+                while got == 0:
+                    victim = self._youngest_other(i)
+                    if victim is None:
+                        break
+                    self._preempt(victim)
+                    got = self._ensure_pages(l, 1)
+                if got:
+                    ready.add(i)
+            live = [(i, l) for i, l in decode
+                    if i in ready and self.lanes[i] is l]
+            if not live:
+                return set()
+            plan = {i: 1 for i, _ in live}
+            # lanes compact into a bucketed width (requests own pages, not
+            # batch rows, so a half-empty batch never pays full-width compute)
+            width = width_bucket(len(live), self.b)
+            rows = [(r, i, l) for r, (i, l) in enumerate(live)]
+            span.update(rows=len(rows), t_step=1)
+            t0 = time.perf_counter()
+            args = self._pack_step(rows, plan, width, 1, "decode")
+        logits, self.caches = self._dispatch(
+            f"paged_step[{width}x1]", "decode", self._step, self.params,
+            self.caches, *args)
+        logits = self._read_back(logits, "decode")
         now = time.perf_counter()
-        if self._tr.enabled:
-            extra = ({"est_pj": self._hw_prices["decode"][0] * len(live)}
-                     if self.hw is not None else {})
-            self._tr.complete("decode", SCHED_TRACK, t0, now - t0,
-                              lanes=len(live), width=width, **extra)
-        for r, i, l in rows:
-            l.pos += 1
-            self._hw_charge(l.req, "decode", 1)
-            self._c_ctx.inc()
-            self._maybe_cache_prefix(l)  # before _sample can free the pages
-            self._sample(i, l, logits[r], now)
+        with self._tr.phase("sched.sample", phase="decode"):
+            if self._tr.enabled:
+                extra = ({"est_pj": self._hw_prices["decode"][0] * len(live)}
+                         if self.hw is not None else {})
+                self._tr.complete("decode", SCHED_TRACK, t0, now - t0,
+                                  lanes=len(live), width=width, **extra)
+            for r, i, l in rows:
+                l.pos += 1
+                self._hw_charge(l.req, "decode", 1)
+                self._c_ctx.inc()
+                # before _sample can free the pages
+                self._maybe_cache_prefix(l)
+                self._sample(i, l, logits[r], now)
         return {i for i, _ in live}
 
     # -- speculative decoding ------------------------------------------------
@@ -1002,9 +1047,10 @@ class PagedScheduler:
                 plain.append((i, l))
         return staged, plain
 
-    def _pack_rows(self, rows, toks, poss, n_rows: int, t_step: int):
+    def _pack_rows(self, rows, toks, poss, n_rows: int, t_step: int, cfg):
         """Assemble one fixed-shape batch from per-lane token/position lists
-        (pad rows/columns carry the garbage position, like _run_batch)."""
+        (pad rows/columns carry the garbage position) and upload it: the
+        step's (tokens, positions, page table, last_idx) on the device."""
         tokens = np.zeros((n_rows, t_step), np.int32)
         positions = np.full((n_rows, t_step), self.pad_pos, np.int32)
         last_idx = np.zeros((n_rows,), np.int32)
@@ -1016,7 +1062,8 @@ class PagedScheduler:
             positions[r, :n] = poss[i]
             last_idx[r] = n - 1
             table[r, : len(l.pages)] = l.pages
-        return tokens, positions, last_idx, table
+        return (jnp.asarray(tokens), mk_positions(cfg, jnp.asarray(positions)),
+                jnp.asarray(table), jnp.asarray(last_idx))
 
     def _run_draft(self, rows, toks, poss, width: int,
                    t_step: int) -> np.ndarray:
@@ -1032,30 +1079,32 @@ class PagedScheduler:
             self._submit_plan("spec_draft", rows, {
                 i: list(poss[i]) + [poss[i][-1] + 1 + k for k in range(g - 1)]
                 for _, i, _ in rows})
-        tokens, positions, last_idx, table = self._pack_rows(
-            rows, toks, poss, width, t_step)
+        with self._tr.phase("sched.pack", phase="spec_draft",
+                            rows=len(rows), t_step=t_step):
+            args = self._pack_rows(rows, toks, poss, width, t_step,
+                                   self._provider.cfg)
         caches = (self.caches if self._provider.shared_cache
                   else self.draft_caches)
-        drafts, new = self._draft_step(
-            self._provider.params, caches, jnp.asarray(tokens),
-            mk_positions(self._provider.cfg, jnp.asarray(positions)),
-            jnp.asarray(table), jnp.asarray(last_idx),
-        )
+        drafts, new = self._dispatch(
+            f"spec_draft[{width}x{t_step}]", "spec_draft", self._draft_step,
+            self._provider.params, caches, *args)
         if self._provider.shared_cache:
             self.caches = new
         else:
             self.draft_caches = new
         self._c_draft_steps.inc(self.spec.gamma)
-        return np.asarray(drafts)
+        return self._read_back(drafts, "spec_draft")
 
     def _run_ingest(self, rows, toks, poss, width: int, t_step: int) -> None:
-        tokens, positions, last_idx, table = self._pack_rows(
-            rows, toks, poss, width, t_step)
-        _, self.draft_caches = self._draft_ingest(
-            self._provider.params, self.draft_caches, jnp.asarray(tokens),
-            mk_positions(self._provider.cfg, jnp.asarray(positions)),
-            jnp.asarray(table), jnp.asarray(last_idx),
-        )
+        """Draft-side context ingestion: its logits stay on the device."""
+        with self._tr.phase("sched.pack", phase="spec_ingest",
+                            rows=len(rows), t_step=t_step):
+            args = self._pack_rows(rows, toks, poss, width, t_step,
+                                   self._provider.cfg)
+        _, self.draft_caches = self._dispatch(
+            f"spec_ingest[{width}x{t_step}]", "spec_ingest",
+            self._draft_ingest, self._provider.params, self.draft_caches,
+            *args)
 
     def _draft_catch_up(self, rows) -> None:
         """Own-cache providers only: chunked ingestion of the context the
@@ -1088,15 +1137,16 @@ class PagedScheduler:
                     t_step: int) -> np.ndarray:
         if self.analysis_debug:
             self._submit_plan("spec_verify", rows, poss)
-        tokens, positions, _, table = self._pack_rows(
-            rows, toks, poss, width, t_step)
-        logits, self.caches = self._verify_step(
-            self.params, self.caches, jnp.asarray(tokens),
-            mk_positions(self.cfg, jnp.asarray(positions)),
-            jnp.asarray(table),
-        )
+        with self._tr.phase("sched.pack", phase="spec_verify",
+                            rows=len(rows), t_step=t_step):
+            tokens, positions, table, _ = self._pack_rows(
+                rows, toks, poss, width, t_step, self.cfg)
+        logits, self.caches = self._dispatch(
+            f"spec_verify[{width}x{t_step}]", "spec_verify",
+            self._verify_step, self.params, self.caches, tokens, positions,
+            table)
         self._c_verify_steps.inc()
-        return np.asarray(logits)  # [width, t_step, V]
+        return self._read_back(logits, "spec_verify")  # [width, t_step, V]
 
     def _spec_phase(self, staged) -> set:
         """One speculative round for the staged lanes: gamma batched draft
@@ -1143,35 +1193,37 @@ class PagedScheduler:
                                    pow2_bucket(g + 1))
         now = time.perf_counter()
         out = set()
-        for r, i, l in rows:
-            verify = [int(np.argmax(vlogits[r, j])) for j in range(g + 1)]
-            m = greedy_accept(drafts[i], verify)
-            # charge the round's executed work BEFORE _accept_tokens: a lane
-            # finishing mid-round observes req_hw_pj with this round included
-            round_pj = (self._hw_charge(l.req, "draft", feed[i] + g - 1)
-                        + self._hw_charge(l.req, "verify", g + 1))
-            emitted = self._accept_tokens(i, l, verify[:m], now)
-            l.pos = start_pos[i] + emitted
-            # own-cache draft KV is valid for the matched prefix only
-            l.draft_pos = min(start_pos[i] + g, l.pos)
-            self._c_ctx.inc(emitted)
-            self._c_spec_rounds.inc()
-            self._c_drafted.inc(g)
-            self._c_accepted.inc(m - 1)
-            if m == g + 1:
-                self._c_bonus.inc()
-            if self._tr.enabled:
-                extra = ({"est_pj": round_pj}
-                         if self.hw is not None else {})
-                self._tr.complete("spec_round", request_track(l.req.uid),
-                                  t0, now - t0, drafted=g, accepted=m - 1,
-                                  emitted=emitted, **extra)
-            self._update_spec_state(l.req.uid, (m - 1) / g)
-            if self.lanes[i] is l:  # still running: release rejected pages
-                kv_rollback(self.pool, l.pages, ckpts[i],
-                            keep=pages_for(l.pos, self.page_size))
-                self._maybe_cache_prefix(l)
-            out.add(i)
+        with self._tr.phase("sched.sample", phase="spec"):
+            for r, i, l in rows:
+                verify = [int(np.argmax(vlogits[r, j])) for j in range(g + 1)]
+                m = greedy_accept(drafts[i], verify)
+                # charge the round's executed work BEFORE _accept_tokens: a
+                # lane finishing mid-round observes req_hw_pj with this round
+                # included
+                round_pj = (self._hw_charge(l.req, "draft", feed[i] + g - 1)
+                            + self._hw_charge(l.req, "verify", g + 1))
+                emitted = self._accept_tokens(i, l, verify[:m], now)
+                l.pos = start_pos[i] + emitted
+                # own-cache draft KV is valid for the matched prefix only
+                l.draft_pos = min(start_pos[i] + g, l.pos)
+                self._c_ctx.inc(emitted)
+                self._c_spec_rounds.inc()
+                self._c_drafted.inc(g)
+                self._c_accepted.inc(m - 1)
+                if m == g + 1:
+                    self._c_bonus.inc()
+                if self._tr.enabled:
+                    extra = ({"est_pj": round_pj}
+                             if self.hw is not None else {})
+                    self._tr.complete("spec_round", request_track(l.req.uid),
+                                      t0, now - t0, drafted=g, accepted=m - 1,
+                                      emitted=emitted, **extra)
+                self._update_spec_state(l.req.uid, (m - 1) / g)
+                if self.lanes[i] is l:  # still running: release rejected pages
+                    kv_rollback(self.pool, l.pages, ckpts[i],
+                                keep=pages_for(l.pos, self.page_size))
+                    self._maybe_cache_prefix(l)
+                out.add(i)
         return out
 
     def _accept_tokens(self, i: int, lane: _Lane, tokens, now: float) -> int:

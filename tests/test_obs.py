@@ -1,9 +1,14 @@
 """Observability layer: registry/instrument semantics, trace ring + span
-balance, Chrome-trace / Prometheus export validity, and the two serving
+balance, Chrome-trace / Prometheus export validity, the two serving
 acceptance properties — tokens bit-identical with tracing on/off, and trace
-spans reconstructing TTFT/ITL exactly from the shared perf_counter clock."""
+spans reconstructing TTFT/ITL exactly from the shared perf_counter clock —
+and the scheduler's phase spans, recorded on both the recorder's and the
+profiler's clock."""
 import dataclasses
+import glob
 import json
+import re
+import time
 
 import jax
 import numpy as np
@@ -24,6 +29,7 @@ from repro.obs import (
     validate_chrome_trace,
     validate_prometheus_text,
 )
+from repro.obs import SCHED_TRACK
 from repro.obs import check as obs_check
 from repro.serve.engine import Request, ServeEngine
 
@@ -131,6 +137,62 @@ def test_disabled_tracer_records_nothing():
     tr.instant("b", "t")
     tr.end("a", "t")
     assert len(tr) == 0 and tr.span_balance() == {}
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the name and
+    the perf_counter interval of every annotation entered."""
+
+    def __init__(self):
+        self.spans = []
+        rec = self
+
+        class Annotation:
+            def __init__(self, name, **kw):
+                self.name = name
+
+            def __enter__(self):
+                self.t0 = time.perf_counter()
+                return self
+
+            def __exit__(self, *exc):
+                rec.spans.append((self.name, self.t0, time.perf_counter()))
+                return False
+
+        self.cls = Annotation
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    return ann
+
+
+def test_phase_records_one_span_on_both_clocks(annotations):
+    tr = TraceRecorder()
+    with tr.phase("sched.pack", phase="decode") as args:
+        args.update(rows=3)  # an arg learned inside the span
+    (ev,) = tr.events
+    assert (ev.name, ev.ph, ev.track) == ("sched.pack", "X", SCHED_TRACK)
+    assert ev.args == {"phase": "decode", "rows": 3}
+    ((name, a0, a1),) = annotations.spans
+    assert name == "sched.pack"
+    assert a0 <= ev.ts and ev.ts + ev.dur <= a1  # the same interval
+    with pytest.raises(RuntimeError):
+        with tr.phase("sched.sample"):
+            raise RuntimeError("body failed")
+    assert [e.name for e in tr.events][-1] == "sched.sample"
+    assert [a[0] for a in annotations.spans][-1] == "sched.sample"
+
+
+def test_disabled_phase_is_one_shared_null_context(annotations):
+    tr = TraceRecorder(enabled=False)
+    ctx = tr.phase("sched.tick")
+    assert tr.phase("sched.d2h", bytes=4) is ctx  # nothing built per call
+    with ctx as args:
+        args.update(rows=2)
+    assert len(tr) == 0 and annotations.spans == []
 
 
 # ---------------------------------------------------------------------------
@@ -259,6 +321,10 @@ def test_tokens_and_counters_identical_tracing_on_off(setup):
         _comparable_registry_view(eng_off.obs.registry)
     assert len(eng_off.obs.tracer) == 0  # off really is off
     assert len(eng_on.obs.tracer) > 0
+    # the read-back is split into wait + copy on both sides alike
+    for eng in (eng_on, eng_off):
+        assert eng.obs.registry.get("sched_d2h_bytes").total > 0
+        assert eng.obs.registry.get("sched_device_calls").total > 0
 
 
 def test_span_balance_through_preempt_defrag_spec_stress(setup):
@@ -294,10 +360,133 @@ def test_span_balance_through_preempt_defrag_spec_stress(setup):
     assert m["pool"]["used_pages"] == 0
     assert eng.obs.tracer.span_balance() == {}
     assert validate_chrome_trace(chrome_trace(eng.obs.tracer)) == []
+    ticks, phases = _phase_spans(eng.obs.tracer)
+    names = {re.sub(r"\[.*", "", e.name) for e in phases}
+    assert {"spec_draft", "spec_verify", "sched.sample"} <= names
+    assert any((e.args or {}).get("phase") == "spec" for e in phases
+               if e.name == "sched.sample")
     snap = eng.metrics_snapshot()
+    assert snap["sched_d2h_bytes{phase=spec_verify}"] > 0
     assert snap["sched_preemptions"] >= 1
     assert snap["spec_rounds"] > 0
     assert validate_prometheus_text(prometheus_text(eng.obs.registry)) == []
+
+
+PHASES = ("sched.admit", "sched.pack", "sched.wait", "sched.d2h",
+          "sched.sample", "sched.retire")
+DISPATCH = re.compile(r"^(paged_step|spec_draft|spec_ingest|spec_verify)"
+                      r"\[(\d+)x(\d+)\]$")
+
+
+def _phase_spans(tracer):
+    """The ``sched.tick`` spans and the phase spans inside them, checked:
+    every phase lies inside exactly one tick, and no two phases overlap."""
+    xs = [e for e in tracer.events if e.ph == "X" and e.track == SCHED_TRACK]
+    ticks = [e for e in xs if e.name == "sched.tick"]
+    phases = sorted((e for e in xs
+                     if e.name in PHASES or DISPATCH.match(e.name)),
+                    key=lambda e: e.ts)
+    for e in phases:
+        inside = [t for t in ticks
+                  if t.ts <= e.ts and e.ts + e.dur <= t.ts + t.dur]
+        assert len(inside) == 1, f"{e.name} at {e.ts}: {len(inside)} ticks"
+    for a, b in zip(phases, phases[1:]):
+        assert a.ts + a.dur <= b.ts, f"{a.name} overlaps {b.name}"
+    return ticks, phases
+
+
+def test_sched_phase_spans_tile_each_tick(setup):
+    """One ``sched.tick`` per tick; admit, pack, dispatch, wait, d2h, sample
+    and retire follow each other inside it without overlap; the d2h counter
+    counts the rows x vocab float32 logits of every call."""
+    cfg, params, prompts = setup
+    eng, _ = _serve(cfg, params, prompts, trace=True)
+    tr = eng.obs.tracer
+    ticks, phases = _phase_spans(tr)
+    assert len(ticks) == sum(e.name == "tick" for e in tr.events) \
+        == eng.metrics()["steps"]
+    assert tr.span_balance() == {}
+    for t in ticks:
+        mine = [e.name for e in phases
+                if t.ts <= e.ts < t.ts + t.dur]
+        assert mine[0] == "sched.admit" and mine[-1] == "sched.retire"
+        assert mine.count("sched.admit") == mine.count("sched.retire") == 1
+    reg = eng.obs.registry
+    calls, d2h, pack = {}, {}, None
+    for k, e in enumerate(phases):
+        if e.name == "sched.pack":
+            pack = e.args["phase"]
+        m = DISPATCH.match(e.name)
+        if m:
+            # dispatch → wait → copy, each call
+            assert [x.name for x in phases[k + 1 : k + 3]] == \
+                ["sched.wait", "sched.d2h"]
+            nbytes = int(m.group(2)) * cfg.vocab * 4  # float32 logits
+            assert phases[k + 2].args["bytes"] == nbytes
+            calls[pack] = calls.get(pack, 0) + 1
+            d2h[pack] = d2h.get(pack, 0) + nbytes
+    assert set(calls) == {"prefill", "decode"}
+    for ph in calls:
+        assert reg.get("sched_device_calls").value(phase=ph) == calls[ph]
+        assert reg.get("sched_d2h_bytes").value(phase=ph) == d2h[ph]
+
+
+def test_queue_wait_is_admission_minus_submit(setup):
+    cfg, params, prompts = setup
+    eng, _ = _serve(cfg, params, prompts, trace=True)
+    submit, waits = {}, []
+    for ev in eng.obs.tracer.events:
+        if ev.name == "submit":
+            submit[ev.track] = ev.ts
+        elif ev.name == "running" and ev.ph == "B":
+            assert ev.args["queue_wait_s"] == ev.ts - submit[ev.track]
+            waits.append(ev.args["queue_wait_s"])
+    assert len(waits) == len(prompts) and max(waits) > 0
+    h = eng.obs.registry.get("req_queue_wait_seconds")
+    assert h.count() == len(waits)
+    assert h.sum() == pytest.approx(sum(waits))
+
+
+def test_tracing_off_enters_no_annotation(setup, annotations):
+    """Off, the scheduler records no event and enters no TraceAnnotation;
+    on, every annotation it enters has its recorder twin."""
+    cfg, params, prompts = setup
+    eng, _ = _serve(cfg, params, prompts, trace=False)
+    assert len(eng.obs.tracer) == 0 and annotations.spans == []
+    eng, _ = _serve(cfg, params, prompts, trace=True)
+    ticks, phases = _phase_spans(eng.obs.tracer)
+    assert sorted(a[0] for a in annotations.spans) == \
+        sorted(e.name for e in ticks + phases)
+
+
+def test_phase_spans_reach_a_profiler_capture(setup, tmp_path):
+    """The annotations land on the profiler's host plane with the nesting
+    the benchmark's idle-gap labels rely on: each dispatch inside a tick."""
+    from jax.profiler import ProfileData
+
+    cfg, params, prompts = setup
+    eng = ServeEngine(cfg, params, batch_size=2, max_len=32, page_size=8,
+                      trace=True)
+    eng.warmup()
+    for uid, pr in prompts.items():
+        eng.submit(Request(uid=uid, prompt=pr, max_new_tokens=MAX_NEW))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith(("sched.", "paged_step["))]
+    ticks = [s for s in spans if s[0] == "sched.tick"]
+    assert len(ticks) == eng.metrics()["steps"]
+    steps = [s for s in spans if s[0].startswith("paged_step[")]
+    assert steps and all(
+        any(t[1] <= s[1] and s[2] <= t[2] for t in ticks) for s in steps)
+    assert {s[0] for s in spans} >= set(PHASES)
 
 
 def test_trace_reconstructs_ttft_itl_exactly(setup):
