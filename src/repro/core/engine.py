@@ -815,11 +815,12 @@ def dense(x: jax.Array, w) -> jax.Array:
 class AttnBackendSpec:
     """One execution of the paged-attention read.
 
-    fn: ``(q [B,T,H,hd], k_pool, v_pool [P,ps,kv,hd], page_table [B,W],
-    tpos [B,T], *, softmax_dtype, mask_mode, k_scale=None, v_scale=None) →
-    [B,T,H,hd]`` — the attention context over an already-written pool,
-    ragged-masked by ``tpos``.  Quantized pools (int8 codes / packed int4)
-    pass their in-page dequant scales ``[P, ps, kv, 1]``; both backends
+    fn: ``(q [B,T,H,hd], k_pool, v_pool [(L,)P,ps,kv,hd], page_table [B,W],
+    tpos [B,T], *, softmax_dtype, mask_mode, k_scale=None, v_scale=None,
+    layer=None) → [B,T,H,hd]`` — the attention context over an
+    already-written pool, ragged-masked by ``tpos``; a period-stacked 5-D
+    pool is read at ``layer``.  Quantized pools (int8 codes / packed int4)
+    pass their in-page dequant scales ``[(L,)P, ps, kv, 1]``; both backends
     dequantize with the same elementwise formula (``kv_quant``), so decoded
     tokens stay bit-identical across backends on quantized pages too.
     """

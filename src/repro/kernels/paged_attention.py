@@ -9,6 +9,12 @@ scalar-prefetched page table, so each grid step DMAs exactly ONE physical
 page into VMEM. Pages the table does not name are never touched, and the
 dense ``[B, S, kv, hd]`` view never exists in HBM.
 
+The pool may be the whole period stack ``[L, n_pages, ps, kv, hd]`` with
+the layer as a second scalar-prefetch operand: the page index map then
+reads ``(layer, table[b, w])``, so the model's layer scan hands the kernel
+the stacked pool it updates in place and no layer's pool is ever sliced
+out. A 4-D ``[n_pages, ps, kv, hd]`` pool is the one-layer case.
+
 Each step stages its page's K/V rows (dequantized in-register for int8 /
 int4 pools) at their logical offset in a VMEM row buffer, which Pallas
 persists across the sequential grid. The row's last page runs the epilogue:
@@ -64,6 +70,7 @@ def _default_interpret() -> bool:
 
 def _paged_attn_kernel(
     table_ref,  # scalar-prefetch: [B, W] page table (SMEM)
+    layer_ref,  # scalar-prefetch: [1] layer of the stacked pool (SMEM)
     q_ref,      # [1, kv, T*G, hd] this row's queries, grouped by KV head
     tpos_ref,   # [1, T*G, 1] temporal position of each query row
     k_ref,      # [1, ps, kv, hd(/2)] — ONE physical K page, chosen by table
@@ -77,7 +84,7 @@ def _paged_attn_kernel(
     mask_mode: str,
     kv_fmt: str,
 ):
-    del table_ref  # consumed by the BlockSpec index maps
+    del table_ref, layer_ref  # consumed by the BlockSpec index maps
     if kv_fmt == "fp":
         o_ref, k_scr, v_scr = rest
     else:
@@ -124,15 +131,16 @@ def _paged_attn_kernel(
 
 def paged_attention(
     q: jax.Array,           # [B, T, H, hd]
-    k_pool: jax.Array,      # [n_pages, ps, kv, hd]  (hd//2 for packed int4)
-    v_pool: jax.Array,      # [n_pages, ps, kv, hd]
+    k_pool: jax.Array,      # [(L,) n_pages, ps, kv, hd]  (hd//2 for int4)
+    v_pool: jax.Array,      # [(L,) n_pages, ps, kv, hd]
     page_table: jax.Array,  # [B, W] int32 physical page ids
     tpos: jax.Array,        # [B, T] int32 temporal positions (pad -> pad_pos)
     *,
     softmax_dtype="float32",
     mask_mode: str = "where",
-    k_scale: jax.Array | None = None,  # [n_pages, ps, kv, 1] in-page scales
+    k_scale: jax.Array | None = None,  # [(L,) n_pages, ps, kv, 1] scales
     v_scale: jax.Array | None = None,  # (quantized pools only)
+    layer: jax.Array | int | None = None,  # which L of a 5-D pool
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused paged-attention read: returns ``[B, T, H, hd]`` context.
@@ -147,9 +155,20 @@ def paged_attention(
     index map as the page walk — each grid step DMAs one codes page plus
     its ``[ps, kv, 1]`` scale sliver — and dequantization happens
     in-register with the gather backend's exact elementwise formula.
+
+    A 5-D pool is the period stack; ``layer`` names the layer to read, and
+    only that layer's scale pool is widened for the kernel.
     """
+    if k_pool.ndim == 4:  # one layer: a stack of one (a free reshape)
+        if layer is not None:
+            raise ValueError("layer given for a 4-D (single-layer) pool")
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    elif layer is None:
+        raise ValueError("a 5-D (period-stacked) pool needs its layer index")
     b, t, h, hd = q.shape
-    _, ps, kv, hd_p = k_pool.shape
+    _, _, ps, kv, hd_p = k_pool.shape
     w = page_table.shape[1]
     if h % kv:
         raise ValueError(f"n_heads={h} not divisible by n_kv_heads={kv}")
@@ -173,28 +192,34 @@ def paged_attention(
         kv_fmt=kv_fmt,
     )
     row_spec = pl.BlockSpec((1, kv, t * g, hd),
-                            lambda bi, wi, tbl: (bi, 0, 0, 0))
-    page_spec = pl.BlockSpec((1, ps, kv, hd_p),
-                             lambda bi, wi, tbl: (tbl[bi, wi], 0, 0, 0))
+                            lambda bi, wi, tbl, lyr: (bi, 0, 0, 0))
+    # The page walk: block index = (layer, table entry for (row, slot)),
+    # the layer dimension squeezed, so the kernel sees one [1, ps, kv, hd]
+    # page as before.
+    page_spec = pl.BlockSpec(
+        (pl.squeezed, 1, ps, kv, hd_p),
+        lambda bi, wi, tbl, lyr: (lyr[0], tbl[bi, wi], 0, 0, 0))
     in_specs = [
         row_spec,
-        pl.BlockSpec((1, t * g, 1), lambda bi, wi, tbl: (bi, 0, 0)),
-        # The page walk: block index = table entry for (row, slot).
+        pl.BlockSpec((1, t * g, 1), lambda bi, wi, tbl, lyr: (bi, 0, 0)),
         page_spec,
         page_spec,
     ]
     operands = [qg, tpos_rows, k_pool, v_pool]
     if kv_fmt != "fp":
-        scale_spec = pl.BlockSpec((1, ps, kv, 1),
-                                  lambda bi, wi, tbl: (tbl[bi, wi], 0, 0, 0))
+        scale_spec = pl.BlockSpec(
+            (pl.squeezed, 1, ps, kv, 1),
+            lambda bi, wi, tbl, lyr: (0, tbl[bi, wi], 0, 0, 0))
         in_specs += [scale_spec, scale_spec]
-        # the TPU kernel compiler loads no float16; widening is exact, and
-        # the scale pools are hd× smaller than the code pools
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        # the TPU kernel compiler loads no float16: widen this layer's scale
+        # pool (exact; hd× smaller than its code pool) as a stack of one
+        operands += [
+            jax.lax.dynamic_index_in_dim(s, layer, keepdims=True)
+            .astype(jnp.float32) for s in (k_scale, v_scale)]
     # staged rows are dequantized, so quantized pools stage at q's dtype
     stage_dtype = k_pool.dtype if kv_fmt == "fp" else q.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, w),
         in_specs=in_specs,
         out_specs=row_spec,
@@ -211,5 +236,6 @@ def paged_attention(
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, kv, t * g, hd), q.dtype),
             interpret=interpret,
-        )(page_table.astype(jnp.int32), *operands)
+        )(page_table.astype(jnp.int32),
+          jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), *operands)
     return ungroup_queries(out, t)

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from repro.core.engine import PackedWeights as _Packed
 from repro.core.engine import da_qkv_matmul
 from repro.core.linear import dense
-from repro.launch.sharding import constrain
+from repro.launch.sharding import constrain, paged_cache_axes
 from repro.models.config import ModelConfig
 from repro.models import kv_quant as _kvq
 from repro.models.layers import apply_rope, rms_norm_headwise, rope_angles
@@ -327,7 +327,7 @@ def _chunked_attention(q, k, v, q_offset: int, chunk: int, unroll: bool = False)
 
 def paged_gather_read(q, k_pool, v_pool, page_table, tpos, *,
                       softmax_dtype="float32", mask_mode: str = "where",
-                      k_scale=None, v_scale=None):
+                      k_scale=None, v_scale=None, layer=None):
     """Gather-based paged-attention read (the ``"gather"`` engine backend).
 
     Gathers each row's page table back into a contiguous ``[B, S, kv, hd]``
@@ -336,19 +336,24 @@ def paged_gather_read(q, k_pool, v_pool, page_table, tpos, *,
     against. ``kpos <= tpos`` masks unwritten cache, pad lanes and the
     garbage column in one comparison.
 
-    Quantized pools pass the in-page scales (``[P, ps, kv, 1]``); the codes
-    and their scales ride the SAME gather and dequantize elementwise
+    A 5-D pool is the period stack ``[L, n_pages, ps, kv, hd]``: ``layer``
+    joins the page table as one more gather index, so no layer's pool is
+    sliced out first.
+
+    Quantized pools pass the in-page scales (``[(L,) P, ps, kv, 1]``); the
+    codes and their scales ride the SAME gather and dequantize elementwise
     (``kv_quant.dequantize_kv``) before the unchanged attention math — an
     elementwise map commutes with the gather, so each gathered element is
     bitwise the value the fused kernel dequantizes in-register.
     """
     b = q.shape[0]
     fmt = _kvq.kv_format(k_pool, k_scale, q.shape[-1])
-    kg = k_pool[page_table]            # [B, W, ps, kv, hd(/2 for int4)]
-    vg = v_pool[page_table]
+    idx = page_table if layer is None else (layer, page_table)
+    kg = k_pool[idx]                   # [B, W, ps, kv, hd(/2 for int4)]
+    vg = v_pool[idx]
     if fmt != "fp":
-        kg = _kvq.dequantize_kv(kg, k_scale[page_table], fmt, q.dtype)
-        vg = _kvq.dequantize_kv(vg, v_scale[page_table], fmt, q.dtype)
+        kg = _kvq.dequantize_kv(kg, k_scale[idx], fmt, q.dtype)
+        vg = _kvq.dequantize_kv(vg, v_scale[idx], fmt, q.dtype)
     kg = kg.reshape(b, -1, kg.shape[-2], kg.shape[-1])
     vg = vg.reshape(b, -1, vg.shape[-2], vg.shape[-1])
     kg = constrain(kg, ("batch", "kv_seq", "kv_heads", "head_dim"))
@@ -363,7 +368,7 @@ def paged_gather_read(q, k_pool, v_pool, page_table, tpos, *,
 
 
 def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
-                     cfg: ModelConfig):
+                     cfg: ModelConfig, layer=None):
     """Page-table-indexed cache write + backend-dispatched attention read.
 
     Writes each token's K/V row at ``(page_table[b, pos // ps], pos % ps)``
@@ -376,6 +381,12 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
     positions inside the garbage column, whose logical positions exceed
     every real ``tpos``, so ``kpos <= tpos`` masks them out of real rows
     exactly as it masks unwritten cache beyond a row's length.
+
+    ``layer`` given, ``cache`` is the whole period stack
+    ``[L, n_pages, ps, kv, hd]`` (the layer scan carries it): the rows
+    scatter at ``[layer, page, slot]`` and the read takes the stack and the
+    layer, so the layer scan updates the pool in place and never copies a
+    layer's pool out or back. Without it, ``cache`` is one layer's pool.
     """
     from repro.core.engine import get_attn_backend, select_attn_backend
 
@@ -385,10 +396,11 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
     b_idx = jnp.arange(b)[:, None]
     page_ids = page_table[b_idx, tpos // ps]          # [B, T] physical pages
     off = tpos % ps
-    pool_axes = ("page", "page_slot", "kv_heads", "head_dim")
+    at = (page_ids, off) if layer is None else (layer, page_ids, off)
+    pool_axes = paged_cache_axes(cache.k.ndim)
     if fmt == "fp":
-        ck = cache.k.at[page_ids, off].set(k.astype(cache.k.dtype))
-        cv = cache.v.at[page_ids, off].set(v.astype(cache.v.dtype))
+        ck = cache.k.at[at].set(k.astype(cache.k.dtype))
+        cv = cache.v.at[at].set(v.astype(cache.v.dtype))
         cks = cvs = None
     else:
         # quantize at scatter time: each row gets its own per-head absmax
@@ -396,10 +408,10 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
         # scatter to the same (page, slot) the codes do
         qk, sk = _kvq.quantize_kv(k, fmt)
         qv, sv = _kvq.quantize_kv(v, fmt)
-        ck = cache.k.at[page_ids, off].set(qk)
-        cv = cache.v.at[page_ids, off].set(qv)
-        cks = cache.k_scale.at[page_ids, off].set(sk)
-        cvs = cache.v_scale.at[page_ids, off].set(sv)
+        ck = cache.k.at[at].set(qk)
+        cv = cache.v.at[at].set(qv)
+        cks = cache.k_scale.at[at].set(sk)
+        cvs = cache.v_scale.at[at].set(sv)
         cks = constrain(cks, pool_axes)
         cvs = constrain(cvs, pool_axes)
     ck = constrain(ck, pool_axes)
@@ -411,7 +423,7 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
     y = get_attn_backend(name).fn(
         q, ck, cv, page_table, tpos,
         softmax_dtype=cfg.softmax_dtype, mask_mode=cfg.attn_mask_mode,
-        k_scale=cks, v_scale=cvs,
+        k_scale=cks, v_scale=cvs, layer=layer,
     )
     return y, new_cache
 
@@ -425,9 +437,13 @@ def attention_forward(
     update_cache: bool = False,
     attn_bias: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ):
     """Train fwd (cache=None), prefill (update_cache), or decode (T small,
-    cache holds the past). Returns (y, new_cache)."""
+    cache holds the past). Returns (y, new_cache).
+
+    ``layer``: with a PagedKVCache, the cache is the period-stacked pool and
+    this is the layer's index in it (see :func:`_paged_attention`)."""
     b, t, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg, positions)
     new_cache = None
@@ -435,7 +451,8 @@ def attention_forward(
         if page_table is None:
             raise ValueError("a PagedKVCache requires a page_table operand")
         tpos = positions[..., 0] if positions.ndim == 3 else positions  # [B,T]
-        y, new_cache = _paged_attention(q, k, v, cache, page_table, tpos, cfg)
+        y, new_cache = _paged_attention(q, k, v, cache, page_table, tpos, cfg,
+                                        layer=layer)
         y = dense(y.reshape(b, t, cfg.q_dim), p["wo"])
         return constrain(y, ("batch", "seq", "embed")), new_cache
     if cache is not None:

@@ -19,7 +19,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch.sharding import constrain
-from repro.models.attention import KVCache, attention_forward, init_attention
+from repro.models.attention import (
+    KVCache,
+    PagedKVCache,
+    attention_forward,
+    init_attention,
+)
 from repro.models.config import ModelConfig
 from repro.models.layers import (
     apply_embed,
@@ -55,14 +60,14 @@ def init_block(key, cfg: ModelConfig, pos: int) -> Params:
 
 
 def block_forward(p, x, cfg: ModelConfig, pos: int, positions, cache,
-                  update_cache, attn_bias=None, page_table=None):
+                  update_cache, attn_bias=None, page_table=None, layer=None):
     mixer = cfg.mixer_kind(pos)
     ffn = cfg.ffn_kind(pos)
     h = apply_norm(p["norm_mixer"], x, cfg)
     if mixer == "attn":
         y, new_cache = attention_forward(
             p["mixer"], h, cfg, positions, cache, update_cache,
-            attn_bias=attn_bias, page_table=page_table,
+            attn_bias=attn_bias, page_table=page_table, layer=layer,
         )
     else:
         y, new_cache = mamba_forward(p["mixer"], h, cfg, cache, update_cache)
@@ -137,6 +142,13 @@ def forward(
     is ``cfg.paged_attn``, resolved per shape bucket by the engine's
     attention-backend registry (``core.engine.select_attn_backend``).
 
+    PagedKVCache pools ride the layer scan's carry, whole and stacked over
+    periods; the scan's only per-layer input for them is the period index,
+    by which each layer writes its rows and reads its pages in place. As a
+    scan input/output (``xs``/``ys``) each layer's pool would be sliced out
+    and written back, two copies of the whole pool per call. Dense
+    KVCache and Mamba caches stay scan inputs/outputs.
+
     last_idx: int32 [B] — per-row index of the last *real* token; the hidden
     state is gathered there before the LM head (the ragged-batch
     generalization of ``last_logit_only``, used by length-bucketed prefill
@@ -162,17 +174,29 @@ def forward(
 
         attn_bias = causal_bias(t)
 
-    def period_fn(h, period_params, period_caches):
+    # paged pools are loop-carried and indexed by layer; other caches scan
+    paged = ({k: c for k, c in caches.items() if isinstance(c, PagedKVCache)}
+             if have_cache else {})
+
+    def period_fn(h, pools, period_params, period_caches, layer):
+        pools = dict(pools)
         new_caches = {}
         for pos in range(period):
             key = f"pos_{pos}"
+            if key in pools:
+                h, pools[key] = block_forward(
+                    period_params[key], h, cfg, pos, positions, pools[key],
+                    update_cache, attn_bias=attn_bias, page_table=page_table,
+                    layer=layer,
+                )
+                continue
             cache = period_caches[key] if have_cache else None
             h, nc = block_forward(
                 period_params[key], h, cfg, pos, positions, cache,
                 update_cache, attn_bias=attn_bias, page_table=page_table,
             )
             new_caches[key] = nc if nc is not None else 0
-        return h, new_caches
+        return h, pools, new_caches
 
     if cfg.remat:
         policy = {
@@ -181,19 +205,27 @@ def forward(
         }[cfg.remat_policy]
         period_fn = jax.checkpoint(period_fn, policy=policy)
 
-    def scan_body(h, xs):
-        period_params, period_caches = xs
-        h, new_caches = period_fn(h, period_params, period_caches)
-        return h, new_caches
+    def scan_body(carry, xs):
+        h, pools = carry
+        period_params, period_caches, layer = xs
+        h, pools, new_caches = period_fn(h, pools, period_params,
+                                         period_caches, layer)
+        return (h, pools), new_caches
 
     if have_cache:
-        xs = (params["periods"], caches)
+        scanned = {k: c for k, c in caches.items() if k not in paged}
     else:
-        dummy = {f"pos_{i}": jnp.zeros((cfg.n_periods,)) for i in range(period)}
-        xs = (params["periods"], dummy)
-    h, new_caches = jax.lax.scan(
-        scan_body, h, xs, unroll=cfg.n_periods if cfg.scan_unroll else 1
+        scanned = {f"pos_{i}": jnp.zeros((cfg.n_periods,))
+                   for i in range(period)}
+    xs = (params["periods"], scanned,
+          jnp.arange(cfg.n_periods, dtype=jnp.int32))
+    (h, paged), new_caches = jax.lax.scan(
+        scan_body, (h, paged), xs,
+        unroll=cfg.n_periods if cfg.scan_unroll else 1,
     )
+    if have_cache:
+        new_caches = {k: paged[k] if k in paged else new_caches[k]
+                      for k in caches}
 
     if last_idx is not None:
         h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)  # [B,1,D]

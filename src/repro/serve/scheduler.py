@@ -370,7 +370,11 @@ class PagedScheduler:
             self._c_compiles.inc()
             return base(*a)
 
-        self._step = jax.jit(counted)
+        # every step takes the KV pools (argument 1) and returns them
+        # updated: donating them lets each call write into the buffers it
+        # was given (callers rebind self.caches / self.draft_caches from the
+        # result and never read the donated arrays again)
+        self._step = jax.jit(counted, donate_argnums=(1,))
 
         # -- speculative decoding (draft -> batched verify) -------------------
         self.spec = spec
@@ -397,7 +401,7 @@ class PagedScheduler:
                 self._c_draft_compiles.inc()
                 return dbase(*a)
 
-            self._draft_step = jax.jit(counted_draft)
+            self._draft_step = jax.jit(counted_draft, donate_argnums=(1,))
             if not self._provider.shared_cache:
                 # chunked draft-side context ingestion (logits discarded) so
                 # long catch-ups ride prefill_chunk-bucketed shapes instead
@@ -408,14 +412,15 @@ class PagedScheduler:
                     self._c_draft_compiles.inc()
                     return ibase(*a)
 
-                self._draft_ingest = jax.jit(counted_ingest)
+                self._draft_ingest = jax.jit(counted_ingest,
+                                             donate_argnums=(1,))
             vbase = make_verify_step(cfg)
 
             def counted_verify(*a):
                 self._c_verify_compiles.inc()
                 return vbase(*a)
 
-            self._verify_step = jax.jit(counted_verify)
+            self._verify_step = jax.jit(counted_verify, donate_argnums=(1,))
 
         # -- hardware cost attribution (repro.obs.hwcost) ---------------------
         # Per-token-pass prices by phase, fixed at init: prefill / decode /

@@ -23,7 +23,6 @@ import dataclasses
 from typing import Any, Callable, Optional, Protocol, runtime_checkable
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import engine
 from repro.core.engine import PackedWeights
@@ -154,23 +153,18 @@ class LayerSkipDraft:
         dcfg = dataclasses.replace(cfg, n_layers=dp * cfg.period)
 
         def step(params, caches, tokens, positions, page_table, last_idx):
+            # the paged pools stay whole: the draft's layer scan indexes
+            # layers 0..dp-1 of them in place and leaves the rest untouched
             head_params = {
                 **params,
                 "periods": jax.tree.map(lambda a: a[:dp], params["periods"]),
             }
-            head_caches = jax.tree.map(lambda a: a[:dp], caches)
-            logits, new_head = forward(
+            logits, caches = forward(
                 head_params, tokens, dcfg, positions=positions,
-                caches=head_caches, update_cache=True,
+                caches=caches, update_cache=True,
                 page_table=page_table, last_idx=last_idx,
             )
-            merged = jax.tree.map(
-                lambda full, part: jnp.concatenate(
-                    [part.astype(full.dtype), full[dp:]], axis=0
-                ),
-                caches, new_head,
-            )
-            return logits[:, 0], merged
+            return logits[:, 0], caches
 
         return step
 

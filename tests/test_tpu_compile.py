@@ -106,3 +106,61 @@ def test_pallas_lut_compiles(one_chip):
         jax.ShapeDtypeStruct((groups, 1 << cfg.group_size, n), jnp.int32,
                              sharding=one_chip))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp16", "int8"])
+def test_paged_step_updates_pool_in_place_on_v5e(one_chip, monkeypatch,
+                                                  kv_dtype):
+    """The serving step with the fused read compiled (not interpreted), its
+    pool donated: no copy, dynamic-slice or dynamic-update-slice the size of
+    one layer's K/V pool or of their whole stack, and every pool leaf
+    aliased to its output. A small model at the kernel's real head widths.
+    (The int8 scale stacks, 1/hd of the codes, still change layout on the
+    way into and out of the layer loop; not asserted here.)"""
+    import dataclasses
+
+    import repro.kernels.paged_attention as pa
+    from repro.configs.registry import ARCHS, reduce_for_smoke
+    from repro.models.model import init_model
+    from repro.serve.kvcache import init_paged_caches
+    from repro.serve.scheduler import make_paged_step
+    from test_paged_inplace import (aliased_pool_params, hlo_shape,
+                                    pool_sized_ops)
+
+    monkeypatch.setattr(pa, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        reduce_for_smoke(ARCHS["qwen3-8b"]), d_model=256, n_heads=H,
+        n_kv_heads=KV, head_dim=HD, d_ff=512, vocab=512,
+        param_dtype="bfloat16", compute_dtype="bfloat16",
+        paged_attn="fused", kv_dtype=kv_dtype)
+    max_len, b = 128, 8
+    n_pages = 1 + b * pages_for(max_len, PS)
+    kvds = None if kv_dtype == "fp16" else {"pos_0": kv_dtype}
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_model(jax.random.key(0),
+                                                       cfg)))
+    caches = on_chip(jax.eval_shape(lambda: init_paged_caches(
+        cfg, n_pages, PS, cfg.dtype(), kv_dtypes=kvds)))
+    leaves = jax.tree.leaves(caches)
+    codes = [caches["pos_0"].k, caches["pos_0"].v]
+    layer = {hlo_shape(a.dtype, s) for a in codes
+             for s in ((1,) + a.shape[1:], a.shape[1:])}
+    stack = {hlo_shape(a.dtype, a.shape) for a in codes}
+    w = table_width(max_len, PS)
+    step = jax.jit(make_paged_step(cfg), donate_argnums=(1,))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    for rows, t in ((b, 1), (4, 16)):
+        hlo = step.lower(params, caches, i32(rows, t), i32(rows, t),
+                         i32(rows, w), i32(rows)).compile().as_text()
+        assert "tpu_custom_call" in hlo
+        assert pool_sized_ops(hlo, layer | stack) == []
+        pools, aliased = aliased_pool_params(
+            hlo, {hlo_shape(a.dtype, a.shape) for a in leaves})
+        assert len(pools) == len(leaves) and pools <= aliased
