@@ -48,10 +48,17 @@ ARCHS: Dict[str, ModelConfig] = {}
 
 def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family config for CPU smoke tests (per the assignment:
-    small layers/width, few experts, tiny vocab; one fwd/train step)."""
+    small layers/width, few experts, tiny vocab; one fwd/train step).
+
+    A config whose attention width differs from its model width
+    (``n_heads * head_dim != d_model``, e.g. mistral-nemo-12b's 4096 against
+    5120) stays so: d_model 80 against 4 heads of 16, so ``wq``/``wo`` and
+    the fused QKV product keep their non-square shapes."""
+    non_square = bool(cfg.n_heads and cfg.head_dim
+                      and cfg.n_heads * cfg.head_dim != cfg.d_model)
     changes: dict = dict(
         n_layers=cfg.period if cfg.period > 1 else 2,
-        d_model=64,
+        d_model=80 if non_square else 64,
         vocab=97,
         param_dtype="float32",
         compute_dtype="float32",

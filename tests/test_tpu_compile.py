@@ -108,15 +108,22 @@ def test_pallas_lut_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", ["fp16", "int8"])
+@pytest.mark.parametrize("kv_dtype,d_model", [
+    pytest.param("fp16", 256, id="fp16"),
+    pytest.param("int8", 256, id="int8"),
+    pytest.param("fp16", H * HD + 64, id="fp16-model-wider-than-heads"),
+])
 def test_paged_step_updates_pool_in_place_on_v5e(one_chip, monkeypatch,
-                                                  kv_dtype):
+                                                  kv_dtype, d_model):
     """The serving step with the fused read compiled (not interpreted), its
     pool donated: no copy, dynamic-slice or dynamic-update-slice the size of
     one layer's K/V pool or of their whole stack, and every pool leaf
-    aliased to its output. A small model at the kernel's real head widths.
-    (The int8 scale stacks, 1/hd of the codes, still change layout on the
-    way into and out of the layer loop; not asserted here.)"""
+    aliased to its output. A small model at the kernel's real head widths,
+    narrower than its heads (d_model 256) or, as mistral-nemo-12b (5120
+    against 32 x 128), wider: then ``wq`` narrows, ``wo`` widens and the
+    fused QKV product has K != N. (The int8 scale stacks, 1/hd of the
+    codes, still change layout on the way into and out of the layer loop;
+    not asserted here.)"""
     import dataclasses
 
     import repro.kernels.paged_attention as pa
@@ -129,7 +136,7 @@ def test_paged_step_updates_pool_in_place_on_v5e(one_chip, monkeypatch,
 
     monkeypatch.setattr(pa, "_default_interpret", lambda: False)
     cfg = dataclasses.replace(
-        reduce_for_smoke(ARCHS["qwen3-8b"]), d_model=256, n_heads=H,
+        reduce_for_smoke(ARCHS["qwen3-8b"]), d_model=d_model, n_heads=H,
         n_kv_heads=KV, head_dim=HD, d_ff=512, vocab=512,
         param_dtype="bfloat16", compute_dtype="bfloat16",
         paged_attn="fused", kv_dtype=kv_dtype)
