@@ -44,6 +44,7 @@ from repro.serve.scheduler import (  # noqa: F401  (Request re-exported)
     latency_metrics,
     mk_positions,
     pow2_bucket,
+    sample_keys,
 )
 
 
@@ -240,7 +241,8 @@ class _SlotRuntime:
             if self.greedy:
                 tok = int(nxt[i])
             else:
-                key = jax.random.key((req.uid << 20) + len(req.generated))
+                key = jax.random.wrap_key_data(jnp.asarray(sample_keys(
+                    (req.uid << 20) + len(req.generated))[0]))
                 tok = int(jax.random.categorical(key, logits[i]))
             if req.token_times:
                 self._h_itl.observe(now - req.token_times[-1])

@@ -185,8 +185,8 @@ def width_bucket(n: int, b: int) -> int:
     return b
 
 
-def make_paged_step(cfg: ModelConfig):
-    """The unified serve step: (params, caches, tokens [B,T], positions,
+def make_paged_logits(cfg: ModelConfig):
+    """The paged forward: (params, caches, tokens [B,T], positions,
     page_table [B,W], last_idx [B]) → (logits [B,V], caches). T=1 is pure
     decode; T>1 coalesces prefill chunks with decoding lanes (their single
     real token rides in column 0, pad columns write to the garbage page)."""
@@ -197,6 +197,45 @@ def make_paged_step(cfg: ModelConfig):
             update_cache=True, page_table=page_table, last_idx=last_idx,
         )
         return logits[:, 0], caches
+
+    return step
+
+
+def sample_keys(seeds) -> np.ndarray:
+    """The uint32 [n, 2] words of the threefry keys of 64-bit ``seeds``
+    (high word, low word): what the sampling step wraps back into keys."""
+    s = np.asarray(seeds, np.uint64).reshape(-1)
+    return np.stack([s >> np.uint64(32), s & np.uint64(0xFFFFFFFF)],
+                    axis=-1).astype(np.uint32)
+
+
+def make_paged_step(cfg: ModelConfig):
+    """The unified serve step: the paged forward (``make_paged_logits``)
+    ending in the greedy sampler, so a call returns the first argmax of
+    each row's logits as one int32 token id per row ([B]) and the caches;
+    the logits never leave the device."""
+    logits_step = make_paged_logits(cfg)
+
+    def step(params, caches, tokens, positions, page_table, last_idx):
+        logits, caches = logits_step(params, caches, tokens, positions,
+                                     page_table, last_idx)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+
+    return step
+
+
+def make_paged_sampling_step(cfg: ModelConfig):
+    """``make_paged_step`` drawing ``jax.random.categorical`` from each
+    row's logits instead of taking the argmax, with a trailing ``keys``
+    [B, 2] uint32 argument (``sample_keys``): one key a row."""
+    logits_step = make_paged_logits(cfg)
+
+    def step(params, caches, tokens, positions, page_table, last_idx, keys):
+        logits, caches = logits_step(params, caches, tokens, positions,
+                                     page_table, last_idx)
+        ids = jax.vmap(jax.random.categorical)(
+            jax.random.wrap_key_data(keys), logits)
+        return ids.astype(jnp.int32), caches
 
     return step
 
@@ -363,7 +402,8 @@ class PagedScheduler:
         self._c_verify_compiles = reg.counter(
             "spec_verify_compiles", "verify-step shape compiles")
         self._start_t: Optional[float] = None
-        base = make_paged_step(cfg)
+        base = (make_paged_step(cfg) if greedy
+                else make_paged_sampling_step(cfg))
 
         def counted(*a):
             # trace-time side effect = 1 per bucket
@@ -858,7 +898,16 @@ class PagedScheduler:
         poss = {i: range(l.pos, l.pos + plan[i]) for _, i, l in rows}
         if self.analysis_debug:
             self._submit_plan(phase, rows, poss)
-        return self._pack_rows(rows, toks, poss, n_rows, t_step, self.cfg)
+        args = self._pack_rows(rows, toks, poss, n_rows, t_step, self.cfg)
+        if self.greedy:
+            return args
+        # each row's key is that of the 64-bit seed (uid << 20) + tokens
+        # generated so far, the index of the token it samples; pad rows
+        # draw from key 0
+        seeds = np.zeros((n_rows,), np.uint64)
+        for r, _, l in rows:
+            seeds[r] = (l.req.uid << 20) + len(l.req.generated)
+        return args + (jnp.asarray(sample_keys(seeds)),)
 
     def _dispatch(self, name: str, phase: str, fn, *args):
         """Issue one device call under its own span (``name`` carries the
@@ -871,7 +920,7 @@ class PagedScheduler:
     def _read_back(self, out, phase: str) -> np.ndarray:
         """The call's synchronisation point, in two spans: ``sched.wait``
         until the device has computed ``out``, then ``sched.d2h`` for the
-        copy to the host."""
+        copy to the host (a plain step's ``out`` is its int32 token ids)."""
         with self._tr.phase("sched.wait"):
             jax.block_until_ready(out)
         with self._tr.phase("sched.d2h", bytes=out.nbytes):
@@ -930,10 +979,10 @@ class PagedScheduler:
             t0 = time.perf_counter()
             args = self._pack_step(rows, plan, self.prefill_lanes, t_step,
                                    "prefill")
-        logits, self.caches = self._dispatch(
+        ids, self.caches = self._dispatch(
             f"paged_step[{self.prefill_lanes}x{t_step}]", "prefill",
             self._step, self.params, self.caches, *args)
-        logits = self._read_back(logits, "prefill")
+        ids = self._read_back(ids, "prefill")
         now = time.perf_counter()
         with self._tr.phase("sched.sample", phase="prefill"):
             if self._tr.enabled:
@@ -952,7 +1001,7 @@ class PagedScheduler:
                 # before _sample can free the pages
                 self._maybe_cache_prefix(l)
                 if l.remaining == 0:  # chunk covered the last unseen token
-                    self._sample(i, l, logits[r], now)
+                    self._sample(i, l, ids[r : r + 1], now)
         return {i for _, i, _ in rows}
 
     def _decode_phase(self, decode) -> set:
@@ -984,10 +1033,10 @@ class PagedScheduler:
             span.update(rows=len(rows), t_step=1)
             t0 = time.perf_counter()
             args = self._pack_step(rows, plan, width, 1, "decode")
-        logits, self.caches = self._dispatch(
+        ids, self.caches = self._dispatch(
             f"paged_step[{width}x1]", "decode", self._step, self.params,
             self.caches, *args)
-        logits = self._read_back(logits, "decode")
+        ids = self._read_back(ids, "decode")
         now = time.perf_counter()
         with self._tr.phase("sched.sample", phase="decode"):
             if self._tr.enabled:
@@ -1001,7 +1050,7 @@ class PagedScheduler:
                 self._c_ctx.inc()
                 # before _sample can free the pages
                 self._maybe_cache_prefix(l)
-                self._sample(i, l, logits[r], now)
+                self._sample(i, l, ids[r : r + 1], now)
         return {i for i, _ in live}
 
     # -- speculative decoding ------------------------------------------------
@@ -1284,12 +1333,11 @@ class PagedScheduler:
             self._c_spec_off.inc()
 
     def _sample(self, i: int, lane: _Lane, row: np.ndarray, now: float) -> None:
+        """Emit the token of ``row``, lane ``i``'s row of the step's result
+        (one int32, the id the step sampled): stamp its time, stream it,
+        and retire the lane if it finished."""
         req = lane.req
-        if self.greedy:
-            tok = int(np.argmax(row))
-        else:
-            key = jax.random.key((req.uid << 20) + len(req.generated))
-            tok = int(jax.random.categorical(key, jnp.asarray(row)))
+        tok = int(row[0])
         if not req.generated:
             req.first_token_t = now
             self._h_ttft.observe(now - req.submit_t)
@@ -1346,9 +1394,11 @@ class PagedScheduler:
             positions = jnp.full((bw, ts), self.pad_pos, dtype=jnp.int32)
             table = jnp.full((bw, self.W), GARBAGE_PAGE, dtype=jnp.int32)
             last_idx = jnp.zeros((bw,), jnp.int32)
+            keys = (() if self.greedy
+                    else (jnp.zeros((bw, 2), jnp.uint32),))
             _, self.caches = self._step(
                 self.params, self.caches, tokens,
-                mk_positions(self.cfg, positions), table, last_idx,
+                mk_positions(self.cfg, positions), table, last_idx, *keys,
             )
         n_spec = 0
         if self.spec is not None:

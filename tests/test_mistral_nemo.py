@@ -26,6 +26,7 @@ from repro.configs.registry import ARCHS  # noqa: E402
 from repro.core.engine import x_bits_override  # noqa: E402
 from repro.models.model import forward, init_model  # noqa: E402
 from repro.serve.engine import Request, ServeEngine  # noqa: E402
+from repro.serve.scheduler import make_paged_logits  # noqa: E402
 
 CONFIG = "mistral-nemo-12b"
 TINY = dict(hidden_size=80, intermediate_size=96, num_attention_heads=4,
@@ -100,8 +101,10 @@ def test_reference_matches_program_float_forward():
 
 def _serve(paged_attn, x_bits=None):
     """Serve both prompts through the paged runtime from a frozen DA
-    artifact, keeping every logits row the step returned: [(uid, position,
-    logits)], and the sequences prompt ++ served tokens."""
+    artifact, keeping the logits of every row the step computed (the step
+    returns token ids, so the spy reads the same call's logits through
+    ``make_paged_logits`` first): [(uid, position, logits)], and the
+    sequences prompt ++ served tokens."""
     model, cfg = _tiny()
     params = weights.make_params(SEED, model, dtype="float32")
     rng = np.random.default_rng(1)
@@ -113,13 +116,19 @@ def _serve(paged_attn, x_bits=None):
                           page_size=PAGE, prefill_chunk=CHUNK,
                           paged_attn=paged_attn)
         step = eng._rt._step
+        logits_of = jax.jit(make_paged_logits(eng._rt.cfg))
 
         def spy(params, caches, tokens, positions, table, last_idx):
-            logits, caches = step(params, caches, tokens, positions, table,
+            logits, _ = logits_of(params, caches, tokens, positions, table,
                                   last_idx)
+            ids, caches = step(params, caches, tokens, positions, table,
+                               last_idx)
+            # the served ids are the argmax of the logits kept for the check
+            np.testing.assert_array_equal(np.asarray(ids),
+                                          np.argmax(np.asarray(logits), -1))
             rows.append(tuple(np.asarray(a) for a in (tokens, positions,
                                                       last_idx, logits)))
-            return logits, caches
+            return ids, caches
 
         eng._rt._step = spy
         for uid, p in enumerate(prompts):

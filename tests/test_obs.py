@@ -398,7 +398,7 @@ def _phase_spans(tracer):
 def test_sched_phase_spans_tile_each_tick(setup):
     """One ``sched.tick`` per tick; admit, pack, dispatch, wait, d2h, sample
     and retire follow each other inside it without overlap; the d2h counter
-    counts the rows x vocab float32 logits of every call."""
+    counts the rows int32 token ids of every call."""
     cfg, params, prompts = setup
     eng, _ = _serve(cfg, params, prompts, trace=True)
     tr = eng.obs.tracer
@@ -421,7 +421,7 @@ def test_sched_phase_spans_tile_each_tick(setup):
             # dispatch → wait → copy, each call
             assert [x.name for x in phases[k + 1 : k + 3]] == \
                 ["sched.wait", "sched.d2h"]
-            nbytes = int(m.group(2)) * cfg.vocab * 4  # float32 logits
+            nbytes = int(m.group(2)) * 4  # one int32 token id a row
             assert phases[k + 2].args["bytes"] == nbytes
             calls[pack] = calls.get(pack, 0) + 1
             d2h[pack] = d2h.get(pack, 0) + nbytes

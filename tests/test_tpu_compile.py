@@ -10,6 +10,8 @@ a 1024-token context, and the DA VMMs of one full-width layer.
 The topology is described inside a module fixture (never at import): only
 the test worker that runs this file loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -167,6 +169,10 @@ def test_paged_step_updates_pool_in_place_on_v5e(one_chip, monkeypatch,
         hlo = step.lower(params, caches, i32(rows, t), i32(rows, t),
                          i32(rows, w), i32(rows)).compile().as_text()
         assert "tpu_custom_call" in hlo
+        # the step samples on the chip: one int32 token id a row comes back
+        out = hlo.splitlines()[0].split(")->", 1)[1]
+        assert re.match(r"\((\w+\[[\d,]*\])", out).group(1) == \
+            f"s32[{rows}]"
         assert pool_sized_ops(hlo, layer | stack) == []
         pools, aliased = aliased_pool_params(
             hlo, {hlo_shape(a.dtype, a.shape) for a in leaves})
