@@ -9,7 +9,6 @@ from __future__ import annotations
 
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 

@@ -9,7 +9,6 @@ import traceback
 def main() -> None:
     from benchmarks import (
         da_model_scale,
-        kernel_micro,
         lenet_conv1,
         roofline_table,
         scaling,
@@ -20,7 +19,6 @@ def main() -> None:
         ("table1_comparison (paper Table I)", table1_comparison.main),
         ("scaling (paper Fig. 5)", scaling.main),
         ("lenet_conv1 (paper Fig. 3, §III-C)", lenet_conv1.main),
-        ("kernel_micro", kernel_micro.main),
         ("da_model_scale (beyond-paper)", da_model_scale.main),
         ("roofline_table (§Roofline)", roofline_table.main),
     ]

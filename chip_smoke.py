@@ -202,7 +202,6 @@ def serve(cfg, size: Size, seed: int, rehearse: bool) -> None:
     from repro.core.engine import get_backend, select_attn_backend
     from repro.models.model import count_params, init_model
     from repro.serve.engine import Request, ServeEngine
-    from repro.serve.kvcache import table_width
 
     print(f"model {cfg.name}: d_model={cfg.d_model} heads={cfg.n_heads}/"
           f"{cfg.n_kv_heads} head_dim={cfg.head_dim_} d_ff={cfg.d_ff} "
@@ -231,17 +230,13 @@ def serve(cfg, size: Size, seed: int, rehearse: bool) -> None:
         if not rehearse and p.with_luts:
             raise AssertionError(f"{key}: LUTs at published widths")
 
-    kv_len = table_width(size.max_len, size.page_size) * size.page_size
     want = "fused" if jax.devices()[0].platform == "tpu" else "gather"
-    # the engine's two step shapes: decode rows and 16-token prefill chunks
-    for t, phase in ((1, "decode"), (16, "prefill")):
-        picked = select_attn_backend(eng.cfg.paged_attn, batch=size.batch,
-                                     t=t, kv_len=kv_len)
-        print(f"attention backend ({phase}, T={t}): {picked}")
-        if picked != want:
-            raise AssertionError(
-                f"paged_attn='auto' picked {picked!r} for {phase}; expected "
-                f"{want!r} on {jax.devices()[0].platform}")
+    picked = select_attn_backend(eng.cfg.paged_attn)
+    print(f"attention backend: {picked}")
+    if picked != want:
+        raise AssertionError(
+            f"paged_attn='auto' picked {picked!r}; expected {want!r} on "
+            f"{jax.devices()[0].platform}")
 
     rng = np.random.default_rng(seed)
     for uid in range(size.requests):

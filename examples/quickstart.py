@@ -5,13 +5,13 @@
    (pack_quantized / pack_weights — the once-in-a-lifetime step, §III-A).
 3. Run a bit-serial, multiplier-free, ADC-free VMM through the unified engine
    (§II) — every registered backend is bit-exact against the integer matmul,
-   and mode="auto" picks the backend from the activation/layer shape.
+   and mode="auto" picks the backend from the activation rows and whether
+   the artifact carries LUTs.
 4. Ask the calibrated hardware model what this costs on a ReRAM engine vs the
    bit-slicing baseline (Table I).
 
 Run: PYTHONPATH=src python examples/quickstart.py
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -60,7 +60,7 @@ def main():
     xf = rng.normal(size=(4, 64)).astype(np.float32)
     wf = rng.normal(size=(64, 32)).astype(np.float32)
     pw = pack_weights(jnp.asarray(wf))                       # codes + scale + LUTs
-    y_da = da_matmul(jnp.asarray(xf), pw, mode="auto")       # shape-aware dispatch
+    y_da = da_matmul(jnp.asarray(xf), pw, mode="auto")       # the auto rule
     chosen = select_backend(4, 64, 32, DAConfig(x_signed=True), pw.has_luts)
     rel = np.abs(np.asarray(y_da) - xf @ wf).max() / np.abs(xf @ wf).max()
     print(f"float linear via DA engine (auto -> {chosen}): "

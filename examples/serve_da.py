@@ -6,8 +6,7 @@ serving graph runs the multiplier-free datapath.
 Run: PYTHONPATH=src python examples/serve_da.py [--requests 8] [--mode auto]
 
 ``--mode auto`` runs the per-layer planner: each weight matrix gets its own
-(backend, group size, lut-or-not) decision from measured autotune timings
-with the analytic hardware model as fallback.
+(backend, lut-or-not) decision from the engine's one backend rule.
 
 Freeze-once, serve-many::
 

@@ -32,23 +32,15 @@ name                 needs LUTs  execution
                                 not multiplier-free — never auto-picked)
 ===================  =========  ======================================
 
-**2. The ``"auto"`` policy.**  ``mode="auto"`` picks the backend from the
-``(M, K, N, x_bits)`` shape: shapes are folded into coarse buckets
-(:func:`shape_bucket`), and a measured cost table — produced by
-``benchmarks/engine_autotune.py``, which times every backend per bucket and
-writes a JSON cache — maps each bucket to per-backend µs.  The cheapest
-*eligible* backend wins (LUT modes are only eligible when the packed weights
-carry LUTs; the ``int8`` baseline is never auto-picked because it is not
-multiplier-free).  Without a cache the engine falls back to a deterministic
-heuristic: decode-like shapes (M ≤ 8) with LUTs available read the PMAs
-(``lut``); everything else runs the one-matmul ``bitplane_stacked`` form.
-Regenerate the cache with::
-
-    PYTHONPATH=src python benchmarks/engine_autotune.py        # full
-    PYTHONPATH=src python benchmarks/engine_autotune.py --quick
-
-The cache lives at ``artifacts/engine_autotune.json`` (override with the
-``REPRO_ENGINE_AUTOTUNE`` env var) and is loaded lazily on first dispatch.
+**2. The ``"auto"`` policy.**  ``mode="auto"`` picks the backend by one
+rule, :func:`choose_backend`, from the activation rows M, the contraction K
+and what the artifact carries: with LUTs and M ≤ 8 (decode-like) it reads
+the PMAs (``lut``); otherwise it runs the one-matmul ``bitplane_stacked``
+form.  Only capability-eligible DA backends are considered (LUT modes need
+LUTs in the artifact; the ``int8`` baseline is never auto-picked because it
+is not multiplier-free).  The freeze planner (:mod:`repro.core.freeze`)
+applies the same rule at the expected decode batch, so a layer's planned
+backend is the one call-time dispatch would pick for it.
 
 **3. ``PackedWeights``.**  The single frozen-weight artifact: int8 codes +
 per-column scale + optional LUTs, built ONCE by :func:`pack_weights` (the
@@ -58,22 +50,16 @@ is callable (``packed(x)`` runs the engine), and MoE-style stacked experts
 ``[E, K, N]`` vmap through it unchanged.
 
 This module is the *per-matrix* engine.  The **model-level** entry — walk a
-params pytree, plan a backend/group-size/LUT decision per layer from measured
-+ analytic costs, pack every weight matrix, and serialize the result as a
-servable on-disk artifact — is :mod:`repro.core.freeze` (plan → pack →
-serialize → shard → serve).
+params pytree, plan a backend/LUT decision per layer by the same rule, pack
+every weight matrix, and serialize the result as a servable on-disk artifact
+— is :mod:`repro.core.freeze` (plan → pack → serialize → shard → serve).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import json
-import os
-import pathlib
-import warnings
-import zlib
 from functools import partial
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -156,10 +142,8 @@ def path_entry_name(entry) -> str:
     return str(entry)
 
 
-#: Default LUT budget in cells per matrix, shared by the serving freeze
-#: (pack_weights / freeze_da / freeze_model_da) AND the autotune benchmark —
-#: one constant so "which layers carry LUTs" and "which buckets time LUT
-#: backends" can't drift apart.
+#: Default LUT budget in cells per matrix, shared by pack_weights and the
+#: freeze planner — one constant decides which layers carry LUTs.
 DEFAULT_LUT_LIMIT = 1 << 24
 
 
@@ -251,7 +235,7 @@ class BackendSpec:
     is_da: bool = True
     #: Advisory, not an eligibility gate: the backend contracts on the int8
     #: MXU path (weight codes must fit int8 — guaranteed by the 8-bit
-    #: quantizer). Drives TPU tiling choices and is recorded for autotuning.
+    #: quantizer). Drives TPU tiling choices.
     int8_path: bool = False
     signed_only: bool = False
     max_group_size: int = 16
@@ -308,24 +292,6 @@ def register_backend(name: str, **caps):
 def registered_backends() -> Dict[str, BackendSpec]:
     """Name → spec of every registered backend (the differential-test sweep)."""
     return dict(_REGISTRY)
-
-
-#: Bump when a backend's *implementation* changes performance-relevantly
-#: without a rename — invalidates every autotune cache.
-REGISTRY_VERSION = 1
-
-
-def registry_fingerprint() -> str:
-    """Fingerprint of the backend registry (sorted names + version).
-
-    Stamped into ``artifacts/engine_autotune.json`` by the autotune benchmark;
-    a cache whose fingerprint disagrees was tuned against a different backend
-    set (renamed / added / removed) and its numbers can't be trusted to rank
-    today's registry — the loader warns and falls back to the heuristic
-    instead of raising ``KeyError`` at dispatch time.
-    """
-    blob = f"v{REGISTRY_VERSION}:" + ",".join(sorted(_REGISTRY))
-    return f"{zlib.crc32(blob.encode()):08x}"
 
 
 def get_backend(mode: str) -> BackendSpec:
@@ -404,24 +370,6 @@ def _int8_backend(xq, packed, cfg):
     )
 
 
-def timeable_backends(cfg: DAConfig, has_luts: bool,
-                      include_baselines: bool = False):
-    """Backends worth timing on this host (shared by engine_autotune and
-    kernel_micro so their eligibility rules cannot drift): capability-
-    eligible, DA-only unless baselines are requested, and skipping the
-    Pallas kernels off-TPU, where interpret mode is a correctness tool
-    rather than a timing."""
-    on_tpu = jax.default_backend() == "tpu"
-    for name, spec in sorted(_REGISTRY.items()):
-        if not spec.supports(cfg, has_luts):
-            continue
-        if not (spec.is_da or include_baselines):
-            continue
-        if name.startswith("pallas") and not on_tpu:
-            continue
-        yield spec
-
-
 def jit_backend(spec: BackendSpec, cfg: DAConfig):
     """jit-compiled ``fn(xq, packed)`` for one backend.  ``packed`` is a jit
     *argument*: closing over it would bake the (possibly multi-GB) LUT array
@@ -430,184 +378,39 @@ def jit_backend(spec: BackendSpec, cfg: DAConfig):
 
 
 # ---------------------------------------------------------------------------
-# Shape buckets + measured cost table (the "auto" policy)
+# The "auto" policy: one rule from shape and artifact
 # ---------------------------------------------------------------------------
 
-_M_EDGES: Tuple[Tuple[int, str], ...] = ((8, "dec"), (256, "mid"))
-_KN_EDGES: Tuple[Tuple[int, str], ...] = ((1 << 14, "s"), (1 << 20, "m"))
 
-#: One representative (M, K, N) per (m-bucket, kn-bucket) cell, shared by the
-#: autotune benchmark (what it times) and the dispatch tests (what they probe).
-BUCKET_SHAPES: Dict[str, Tuple[int, int, int]] = {
-    "dec:s": (4, 64, 128),
-    "dec:m": (4, 512, 1024),
-    "dec:l": (4, 2048, 2048),
-    "mid:s": (64, 64, 128),
-    "mid:m": (64, 512, 1024),
-    "mid:l": (64, 2048, 2048),
-    "big:s": (512, 64, 128),
-    "big:m": (512, 512, 1024),
-    "big:l": (512, 2048, 2048),
-}
-
-
-def shape_bucket(m: int, k: int, n: int, x_bits: int) -> str:
-    """Fold (M, K, N, x_bits) into a coarse cost-table key.
-
-    M buckets: decode-like (≤8) / mid (≤256) / big.  K·N buckets: small
-    (≤2^14) / mid (≤2^20) / large.  x_bits is kept exact (4-bit inputs halve
-    the bit-serial cycle count, which shifts the backend ranking).
-    """
-    mb = next((tag for edge, tag in _M_EDGES if m <= edge), "big")
-    kb = next((tag for edge, tag in _KN_EDGES if k * n <= edge), "l")
-    return f"{mb}:{kb}:b{x_bits}"
-
-
-def default_cache_path() -> pathlib.Path:
-    env = os.environ.get("REPRO_ENGINE_AUTOTUNE")
-    if env:
-        return pathlib.Path(env)
-    return (
-        pathlib.Path(__file__).resolve().parents[3]
-        / "artifacts" / "engine_autotune.json"
+def choose_backend(m: int, k: int, cfg: DAConfig, has_luts: bool) -> str:
+    """The one backend rule, shared by call-time dispatch and the freeze
+    planner: decode-like rows (M ≤ 8) read the PMAs when the artifact
+    carries LUTs; everything else runs the one-matmul stacked bit-plane form
+    (W read once — the TPU-shaped mapping).  Only capability-eligible DA
+    backends are considered; if neither named one is eligible, the first
+    eligible name wins."""
+    names = sorted(
+        s.name for s in _REGISTRY.values()
+        if s.is_da and s.supports(cfg, has_luts, k=k)
     )
-
-
-_COST_TABLE: Optional[Dict[str, Dict[str, float]]] = None  # None → not loaded
-
-#: (cache path, tuned-on device) pairs already warned about as foreign.
-_DEVICE_MISMATCH_WARNED: set = set()
-
-
-def load_cost_table(path: Optional[os.PathLike] = None) -> Dict[str, Dict[str, float]]:
-    """Lazily load the autotune cache: {bucket: {backend: µs}}.
-
-    Missing or unreadable caches degrade to the heuristic fallback — the
-    engine never *requires* autotuning to run.  A cache recording a
-    ``device`` other than the current ``jax.default_backend()`` is rejected
-    (a TPU-tuned table would steer CPU dispatch into interpret-mode Pallas
-    kernels, and vice versa); buckets are tuned at one ``group_size`` (the
-    ranking of the storage-free backends is group-independent, and LUT
-    eligibility is re-checked per artifact at dispatch time).
-
-    Only default-path loads populate the process-wide table that ``auto``
-    dispatch reads; loading an explicit ``path`` is read-only (use
-    :func:`set_cost_table` to install such a table deliberately).
-    """
-    global _COST_TABLE
-    if _COST_TABLE is not None and path is None:
-        return _COST_TABLE
-    p = pathlib.Path(path) if path is not None else default_cache_path()
-    table: Dict[str, Dict[str, float]] = {}
-    unknown: set = set()
-    try:
-        raw = json.loads(p.read_text())
-        entries = raw.get("table", raw)
-        device = raw.get("device") if isinstance(raw, dict) else None
-        here = jax.default_backend()
-        if device is not None and device != here:
-            # tuned on different hardware: fall back to the heuristic
-            entries = {}
-            if (str(p), device) not in _DEVICE_MISMATCH_WARNED:
-                _DEVICE_MISMATCH_WARNED.add((str(p), device))
-                warnings.warn(
-                    f"autotune cache {p} was tuned on {device!r} but this "
-                    f"process runs on {here!r}; ignoring it — dispatch uses "
-                    "the heuristic until benchmarks/engine_autotune.py is "
-                    f"run on {here!r} (warned once)",
-                    stacklevel=2,
-                )
-        stamp = raw.get("registry") if isinstance(raw, dict) else None
-        if stamp is not None and stamp != registry_fingerprint():
-            warnings.warn(
-                f"autotune cache {p} was tuned against a different backend "
-                f"registry (stamp {stamp!r} != {registry_fingerprint()!r}); "
-                "ignoring it — re-run benchmarks/engine_autotune.py",
-                stacklevel=2,
-            )
-            entries = {}
-        for bucket, costs in entries.items():
-            if isinstance(costs, dict):
-                # "attn:*" buckets rank attention-read backends, the rest
-                # rank DA VMM backends — each filtered against its registry.
-                reg = _ATTN_REGISTRY if bucket.startswith("attn:") else _REGISTRY
-                unknown.update(b for b in costs if b not in reg)
-                table[bucket] = {
-                    b: float(us) for b, us in costs.items()
-                    if b in reg and isinstance(us, (int, float))
-                }
-        if unknown:
-            warnings.warn(
-                f"autotune cache {p} names unregistered backends "
-                f"{sorted(unknown)}; their timings are dropped (heuristic "
-                "fallback where no eligible backend was timed)",
-                stacklevel=2,
-            )
-    except (OSError, ValueError, AttributeError):
-        table = {}
-    if path is None:
-        _COST_TABLE = table
-    return table
-
-
-def set_cost_table(table: Optional[Dict[str, Dict[str, float]]]) -> None:
-    """Install a cost table in-process (tests / autotune); None → reload."""
-    global _COST_TABLE
-    _COST_TABLE = dict(table) if table is not None else None
-    _BUCKET_MISS_WARNED.clear()  # a new table resets the warn-once dedup
-
-
-#: (bucket, fallback backend) pairs already warned about — the bucket-miss
-#: diagnostic fires once per pair per process, not once per da_matmul call
-#: (a decode loop hits the same bucket thousands of times per second).
-_BUCKET_MISS_WARNED: set = set()
+    if not names:  # unreachable with the built-in backends, but be loud
+        raise ValueError(
+            f"no DA backend supports cfg={cfg} has_luts={has_luts} k={k}"
+        )
+    if has_luts and m <= 8 and "lut" in names:
+        return "lut"
+    if "bitplane_stacked" in names:
+        return "bitplane_stacked"
+    return names[0]
 
 
 def select_backend(
     m: int, k: int, n: int, cfg: DAConfig, has_luts: bool = True
 ) -> str:
-    """The ``"auto"`` policy: cheapest measured eligible DA backend, else the
-    deterministic heuristic.  Always returns a registered, eligible name."""
-    eligible = [
-        s for s in _REGISTRY.values()
-        if s.is_da and s.supports(cfg, has_luts, k=k)
-    ]
-    if not eligible:  # unreachable with the built-in backends, but be loud
-        raise ValueError(
-            f"no DA backend supports cfg={cfg} has_luts={has_luts}"
-        )
-    table = load_cost_table()
-    bucket = shape_bucket(m, k, n, cfg.x_bits)
-    costs = table.get(bucket, {})
-    timed = [s for s in eligible if s.name in costs]
-    if timed:
-        return min(timed, key=lambda s: costs[s.name]).name
-    choice = _fallback_backend(m, cfg, has_luts, eligible)
-    if table and (bucket, choice) not in _BUCKET_MISS_WARNED:
-        # an autotune cache exists but never timed this bucket's eligible
-        # backends: dispatch is running on the heuristic, which is worth one
-        # loud diagnostic — not one per call
-        _BUCKET_MISS_WARNED.add((bucket, choice))
-        warnings.warn(
-            f"autotune cache has no timings for bucket {bucket!r} (eligible: "
-            f"{', '.join(sorted(s.name for s in eligible))}); using the "
-            f"heuristic fallback {choice!r} — re-run "
-            "benchmarks/engine_autotune.py to tune it (warned once per "
-            "bucket/backend)",
-            stacklevel=2,
-        )
-    return choice
-
-
-def _fallback_backend(m, cfg, has_luts, eligible) -> str:
-    """No measurements: decode-like reads the PMAs, everything else runs the
-    one-matmul stacked bit-plane form (W read once — the TPU-shaped mapping)."""
-    names = {s.name for s in eligible}
-    if has_luts and m <= 8 and "lut" in names:
-        return "lut"
-    if "bitplane_stacked" in names:
-        return "bitplane_stacked"
-    return sorted(names)[0]
+    """Call-time ``"auto"`` dispatch of an [M, K] x [K, N] product: the rule
+    of :func:`choose_backend` (N does not enter it).  Always returns a
+    registered, eligible DA backend name."""
+    return choose_backend(m, k, cfg, has_luts)
 
 
 # ---------------------------------------------------------------------------
@@ -807,7 +610,7 @@ def dense(x: jax.Array, w) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Paged-attention read backends — the decode-attention analogue of the DA
 # registry.  The paged runtime has two interchangeable executions of the same
-# attention read over the page pool; dispatch picks per shape bucket.
+# attention read over the page pool; "auto" picks one by platform.
 # ---------------------------------------------------------------------------
 
 
@@ -855,42 +658,20 @@ def get_attn_backend(mode: str) -> AttnBackendSpec:
         raise ValueError(
             f"unknown paged-attention backend {mode!r}; registered: "
             f"{', '.join(sorted(_ATTN_REGISTRY))} (plus 'auto' for "
-            "cost-table / platform dispatch)"
+            "platform dispatch)"
         )
     return _ATTN_REGISTRY[mode]
 
 
-def attn_shape_bucket(batch: int, t: int, kv_len: int) -> str:
-    """Fold a paged-attention call shape into a coarse cost-table key.
-
-    Namespaced ``attn:`` so the same autotune JSON can carry VMM buckets and
-    attention buckets side by side.  T buckets decode-like steps (plain
-    decode and spec draft/verify staging, T ≤ 8) apart from prefill chunks;
-    the KV extent (table width · page size) buckets the read volume.
-    """
-    phase = "dec" if t <= 8 else "pre"
-    kb = "s" if kv_len <= 256 else ("m" if kv_len <= 2048 else "l")
-    return f"attn:{phase}:{kb}"
-
-
-def select_attn_backend(mode: Optional[str], *, batch: int, t: int,
-                        kv_len: int) -> str:
+def select_attn_backend(mode: Optional[str]) -> str:
     """Resolve a ``cfg.paged_attn`` mode to a registered backend name.
 
-    ``"auto"`` reads the autotune cost table's ``attn:*`` bucket for this
-    shape (populated by ``benchmarks/paged_decode.py``); untimed buckets fall
-    back to the platform heuristic — the fused Pallas walk on TPU, the XLA
+    ``"auto"`` (and None) is the fused Pallas page walk on TPU and the XLA
     gather read elsewhere (off-TPU the kernel runs in interpreter mode, a
-    correctness tool rather than a fast path).
-    """
-    mode = "auto" if mode is None else mode
-    if mode != "auto":
-        return get_attn_backend(mode).name
-    costs = load_cost_table().get(attn_shape_bucket(batch, t, kv_len), {})
-    timed = {n: c for n, c in costs.items() if n in _ATTN_REGISTRY}
-    if timed:
-        return min(timed, key=timed.get)
-    return "fused" if jax.default_backend() == "tpu" else "gather"
+    correctness tool rather than a fast path)."""
+    if mode is None or mode == "auto":
+        return "fused" if jax.default_backend() == "tpu" else "gather"
+    return get_attn_backend(mode).name
 
 
 @register_attn_backend(

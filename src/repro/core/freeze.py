@@ -6,12 +6,10 @@ PMA contents, §III-A) — happens once, offline, and inference is shift-and-add
 readout.  This module makes that premise operational at model scale:
 
 1. **Plan** (:func:`plan_model`): for every weight-matrix leaf of a params
-   pytree, choose a backend mode, group size and lut-or-not from the layer's
-   (K, N) shape and the expected decode batch.  Measured autotune timings
-   (``artifacts/engine_autotune.json``) rank the backends when the bucket was
-   tuned on this host; otherwise the analytic hardware cost model
-   (:mod:`repro.core.hwmodel`) ranks them — the DAISM-style "choose the
-   in-memory multiply strategy per layer" policy, never a constant choice.
+   pytree, decide lut-or-not from the layer's (K, N) shape against the LUT
+   cell budget, then pick its backend by the engine's one rule
+   (:func:`repro.core.engine.choose_backend`) at the expected decode batch —
+   the same rule ``mode="auto"`` dispatch applies at call time.
 2. **Pack** (:func:`freeze_model`): run the pre-VMM step per leaf under its
    plan, producing a :class:`DAArtifact` — the packed params pytree plus the
    plan, DA config, and (optionally) the model config.
@@ -32,10 +30,9 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import math
 import os
 import warnings
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 
@@ -44,16 +41,13 @@ from repro.core.engine import (
     DEFAULT_LUT_LIMIT,
     PackedWeights,
     canonical_mode,
+    choose_backend,
     get_backend,
-    load_cost_table,
     lut_cells,
     pack_weights,
     path_entry_name,
     registered_backends,
-    registry_fingerprint,
-    shape_bucket,
 )
-from repro.core.hwmodel import T_ADD_STAGE, T_READ_PIPE
 
 
 def _hwcost():
@@ -95,11 +89,11 @@ class LayerPlan:
     group_size:  rows per PMA for this layer (LUT addressability).
     with_luts:   materialize the weight-sum LUTs (the PMA write) or not.
     k, n:        the weight matrix shape the plan was made for.
-    source:      "measured" (autotune bucket timing), "analytic" (hwmodel
-                 fallback — no timing for this bucket on this host), or
-                 "pinned" (a concrete mode was requested, no planning).
-    est_cost:    the winning backend's estimated cost — µs when measured,
-                 model-ns when analytic, NaN when pinned.
+    source:      "rule" (planned by :func:`~repro.core.engine.choose_backend`),
+                 "pinned" (a concrete mode was requested, no planning) or
+                 "stale" (planned for a backend this build does not
+                 register; demoted to ``mode="auto"`` on load).  Manifests
+                 written by older builds keep the string they recorded.
     kv_dtype:    KV-page precision this layer's cache serves at (recorded on
                  the wk/wv mixer leaves only — ``"fp16"`` | ``"int8"`` |
                  ``"int4"``; None for every non-KV leaf).  Artifacts carry it
@@ -112,22 +106,16 @@ class LayerPlan:
     with_luts: bool
     k: int
     n: int
-    source: str = "analytic"
-    # informational, not identity: NaN (pinned plans) would poison ==
-    est_cost: float = dataclasses.field(default=float("nan"), compare=False)
+    source: str = "rule"
     kv_dtype: Optional[str] = None
 
     def to_json(self) -> dict:
-        d = dataclasses.asdict(self)
-        if not math.isfinite(d["est_cost"]):
-            d["est_cost"] = None  # a bare NaN literal breaks strict JSON
-        return d
+        return dataclasses.asdict(self)
 
     @classmethod
     def from_json(cls, d: dict) -> "LayerPlan":
         d = dict(d)
-        if d.get("est_cost") is None:
-            d["est_cost"] = float("nan")
+        d.pop("est_cost", None)  # written by older builds' cost planner
         return cls(**d)
 
 
@@ -138,8 +126,8 @@ class DAArtifact:
     params:    pytree with :class:`PackedWeights` at every weight-matrix leaf
                (non-VMM leaves stay float).
     plan:      leaf path (``periods/pos_0/mixer/wq``) → :class:`LayerPlan`.
-    da_cfg:    base DAConfig the model was frozen under (per-layer group
-               sizes may differ — each PackedWeights carries its own cfg).
+    da_cfg:    DAConfig the model was frozen under (each PackedWeights also
+               carries its own cfg).
     model_cfg: the ModelConfig needed to rebuild the serving graph, or None
                for bare trees (round-tripped through the manifest).
     hwcost:    :class:`~repro.obs.hwcost.HardwareCostModel` pricing every
@@ -161,39 +149,8 @@ class DAArtifact:
 
 
 # ---------------------------------------------------------------------------
-# The planner: measured costs with the analytic hwmodel as fallback
+# The planner: the engine's backend rule at the expected decode batch
 # ---------------------------------------------------------------------------
-
-
-def analytic_costs(
-    m: int, k: int, n: int, cfg: DAConfig, has_luts: bool
-) -> Dict[str, float]:
-    """Analytic per-backend latency proxies (model-ns) from the hwmodel.
-
-    Used when no autotune measurement covers a layer's bucket.  These are the
-    *paper's hardware* numbers, not host timings: the PMA readout streams
-    ``x_bits`` read cycles per input row (``DADesign.latency_ns``), the
-    one-hot decode touches the full 2^L/L-blown-up LUT per readout, and the
-    storage-free bit-plane forms pay a K·N multiply-accumulate sweep per bit
-    plane (one adder stage per MAC) plus a weight-array read — once per plane
-    for ``bitplane``, once total for ``bitplane_stacked``.  Only the ranking
-    matters; absolute values are model-scale ns.
-    """
-    costs: Dict[str, float] = {}
-    x_bits = cfg.x_bits
-    mac_sweep = float(m) * k * n * T_ADD_STAGE
-    w_read = float(k) * n * T_READ_PIPE
-    if has_luts:
-        d = _hwcost().da_design(k, n, x_bits=x_bits,
-                                group_size=cfg.group_size)
-        readout = m * d.latency_ns()
-        costs["lut"] = readout
-        costs["pallas_lut"] = readout
-        costs["onehot"] = readout * ((1 << cfg.group_size) / cfg.group_size)
-    costs["bitplane"] = x_bits * (mac_sweep + w_read)
-    costs["pallas_bitplane"] = costs["bitplane"]
-    costs["bitplane_stacked"] = x_bits * mac_sweep + w_read
-    return costs
 
 
 def plan_layer(
@@ -202,57 +159,18 @@ def plan_layer(
     da_cfg: DAConfig,
     m_hint: int = 4,
     lut_cell_limit: int = DEFAULT_LUT_LIMIT,
-    cost_table: Optional[Dict[str, Dict[str, float]]] = None,
-    group_size_candidates: Optional[Sequence[int]] = None,
 ) -> LayerPlan:
-    """Choose (mode, group_size, lut-or-not) for one K×N weight matrix.
+    """Choose (mode, lut-or-not) for one K×N weight matrix.
 
-    ``m_hint`` is the expected serving batch (decode M); it selects the cost
-    bucket.  For each candidate group size: decide LUT feasibility against
-    ``lut_cell_limit``, rank the *eligible* DA backends by measured bucket
-    timing when available (``cost_table``, default the process autotune
-    table), else by :func:`analytic_costs`; the cheapest candidate wins, ties
-    to the first (the base group size).  Measured and analytic costs are
-    never compared against each other — a candidate set mixing both ranks
-    measured candidates first (trust timings over models).  Autotune buckets
-    are timed at ONE group size (the base), so only the base candidate may
-    claim measurement provenance; alternative group sizes rank analytically.
+    LUTs are written when their cells at the base group size fit
+    ``lut_cell_limit``; the backend is :func:`choose_backend` at
+    ``m_hint``, the expected serving batch (decode M) — what ``mode="auto"``
+    dispatch picks for a decode call against the packed layer.
     """
-    table = cost_table if cost_table is not None else load_cost_table()
-    candidates = tuple(group_size_candidates or (da_cfg.group_size,))
-    best: Optional[Tuple[int, float, LayerPlan]] = None  # (rank, cost, plan)
-    for gs in candidates:
-        cfg = dataclasses.replace(da_cfg, group_size=gs)
-        with_luts = lut_cells(k, n, gs) <= lut_cell_limit
-        eligible = [
-            s for s in registered_backends().values()
-            if s.is_da and s.supports(cfg, with_luts, k=k)
-        ]
-        if not eligible:
-            continue
-        measured = (table.get(shape_bucket(m_hint, k, n, cfg.x_bits), {})
-                    if gs == da_cfg.group_size else {})
-        timed = {s.name: measured[s.name] for s in eligible
-                 if s.name in measured}
-        if timed:
-            mode = min(timed, key=timed.get)
-            rank, source, cost = 0, "measured", timed[mode]
-        else:
-            analytic = analytic_costs(m_hint, k, n, cfg, with_luts)
-            scored = {s.name: analytic[s.name] for s in eligible
-                      if s.name in analytic}
-            if not scored:  # registry grew a backend the model doesn't know
-                scored = {min(eligible, key=lambda s: s.name).name: 0.0}
-            mode = min(scored, key=scored.get)
-            rank, source, cost = 1, "analytic", scored[mode]
-        plan = LayerPlan(mode=mode, group_size=gs, with_luts=with_luts,
-                         k=k, n=n, source=source, est_cost=cost)
-        if best is None or (rank, cost) < best[:2]:
-            best = (rank, cost, plan)
-    if best is None:  # unreachable with built-in backends, but be loud
-        raise ValueError(f"no DA backend eligible for K={k} N={n} "
-                         f"candidates={candidates}")
-    return best[2]
+    with_luts = lut_cells(k, n, da_cfg.group_size) <= lut_cell_limit
+    return LayerPlan(mode=choose_backend(m_hint, k, da_cfg, with_luts),
+                     group_size=da_cfg.group_size, with_luts=with_luts,
+                     k=k, n=n)
 
 
 def _path_key(path) -> str:
@@ -272,8 +190,6 @@ def plan_model(
     da_cfg: DAConfig = DAConfig(x_signed=True),
     m_hint: int = 4,
     lut_cell_limit: int = DEFAULT_LUT_LIMIT,
-    cost_table: Optional[Dict[str, Dict[str, float]]] = None,
-    group_size_candidates: Optional[Sequence[int]] = None,
 ) -> Dict[str, LayerPlan]:
     """Per-layer plans for every weight-matrix leaf of ``params`` (no packing).
 
@@ -287,8 +203,6 @@ def plan_model(
             plans[_path_key(path)] = plan_layer(
                 int(leaf.shape[-2]), int(leaf.shape[-1]), da_cfg,
                 m_hint=m_hint, lut_cell_limit=lut_cell_limit,
-                cost_table=cost_table,
-                group_size_candidates=group_size_candidates,
             )
     return plans
 
@@ -305,14 +219,12 @@ def freeze_model(
     m_hint: int = 4,
     lut_cell_limit: int = DEFAULT_LUT_LIMIT,
     model_cfg: Any = None,
-    cost_table: Optional[Dict[str, Dict[str, float]]] = None,
-    group_size_candidates: Optional[Sequence[int]] = None,
     pin_modes: bool = True,
     kv_dtype_overrides: Optional[Dict[str, str]] = None,
 ) -> DAArtifact:
     """Walk the param tree; pack every weight leaf under its per-layer plan.
 
-    ``mode="auto"`` runs the planner (measured + analytic costs).  A concrete
+    ``mode="auto"`` runs the planner (:func:`plan_layer`).  A concrete
     ``mode`` (any registered backend, legacy ``da_*`` spellings accepted)
     pins every layer to it — the one-size-fits-all escape hatch.
 
@@ -348,11 +260,8 @@ def freeze_model(
             return leaf
         k, n = int(leaf.shape[-2]), int(leaf.shape[-1])
         if planned:
-            plan = plan_layer(
-                k, n, da_cfg, m_hint=m_hint, lut_cell_limit=lut_cell_limit,
-                cost_table=cost_table,
-                group_size_candidates=group_size_candidates,
-            )
+            plan = plan_layer(k, n, da_cfg, m_hint=m_hint,
+                              lut_cell_limit=lut_cell_limit)
             if pin_modes and not get_backend(plan.mode).needs_luts:
                 # The pinned backend never reads PMAs: materializing them
                 # would write up to 2^L/L× dead cells into every artifact.
@@ -372,9 +281,8 @@ def freeze_model(
                 plan,
                 kv_dtype=(kv_dtype_overrides or {}).get(pos_seg, base_kv))
         plans[_path_key(path)] = plan
-        cfg = dataclasses.replace(da_cfg, group_size=plan.group_size)
         return pack_weights(
-            leaf, cfg,
+            leaf, da_cfg,
             mode=plan.mode if (pin_modes or not planned) else "auto",
             with_luts=plan.with_luts,
         )
@@ -410,9 +318,7 @@ def save_artifact(directory: str, artifact: DAArtifact) -> str:
 
     Atomic (write to ``<dir>.tmp``, fsync, rename) and crc-checked per array
     via the checkpoint layer; the manifest carries the DA config, the full
-    per-layer plan, the model config, and the backend-registry fingerprint
-    so a loader can tell when the plan references backends that no longer
-    exist.
+    per-layer plan and the model config.
     """
     from repro.checkpoint import ckpt
 
@@ -421,7 +327,6 @@ def save_artifact(directory: str, artifact: DAArtifact) -> str:
         "artifact_version": artifact.version,
         "da_cfg": dataclasses.asdict(artifact.da_cfg),
         "plan": {k: p.to_json() for k, p in artifact.plan.items()},
-        "registry": registry_fingerprint(),
     }
     if artifact.hwcost:
         extra["hwcost"] = artifact.hwcost.to_json()

@@ -9,7 +9,6 @@
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.da import DAConfig
 from repro.kernels import ref

@@ -374,10 +374,10 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
     Writes each token's K/V row at ``(page_table[b, pos // ps], pos % ps)``
     in the batch-free pool, then runs the attention read through the engine's
     paged-attention backend registry — ``cfg.paged_attn`` picks the XLA
-    gather read or the fused Pallas page-walk kernel (``"auto"`` defers to
-    the autotune cost table / platform heuristic per shape bucket). One code
-    path serves decode (T=1), chunked prefill (T=chunk, earlier chunks
-    visible through the pool) and any coalesced mix — pad lanes carry
+    gather read or the fused Pallas page-walk kernel (``"auto"``: fused on
+    TPU, gather elsewhere). One code path serves decode (T=1), chunked
+    prefill (T=chunk, earlier chunks visible through the pool) and any
+    coalesced mix — pad lanes carry
     positions inside the garbage column, whose logical positions exceed
     every real ``tpos``, so ``kpos <= tpos`` masks them out of real rows
     exactly as it masks unwritten cache beyond a row's length.
@@ -390,7 +390,7 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
     """
     from repro.core.engine import get_attn_backend, select_attn_backend
 
-    b, t = tpos.shape
+    b = tpos.shape[0]
     ps = cache.page_size
     fmt = _kvq.kv_format(cache.k, cache.k_scale, q.shape[-1])
     b_idx = jnp.arange(b)[:, None]
@@ -417,9 +417,7 @@ def _paged_attention(q, k, v, cache: PagedKVCache, page_table, tpos,
     ck = constrain(ck, pool_axes)
     cv = constrain(cv, pool_axes)
     new_cache = PagedKVCache(k=ck, v=cv, k_scale=cks, v_scale=cvs)
-    name = select_attn_backend(getattr(cfg, "paged_attn", "auto"),
-                               batch=b, t=t,
-                               kv_len=page_table.shape[1] * ps)
+    name = select_attn_backend(getattr(cfg, "paged_attn", "auto"))
     y = get_attn_backend(name).fn(
         q, ck, cv, page_table, tpos,
         softmax_dtype=cfg.softmax_dtype, mask_mode=cfg.attn_mask_mode,

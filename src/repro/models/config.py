@@ -75,8 +75,8 @@ class ModelConfig:
     cache_mode: str = "scatter"       # L9: scatter (ragged rows, general) |
                                       # slice (uniform positions — GSPMD-local
                                       # dynamic_update_slice, no gather)
-    paged_attn: str = "auto"          # paged-attention read: auto (cost-table
-                                      # / platform dispatch) | gather (XLA
+    paged_attn: str = "auto"          # paged-attention read: auto (fused
+                                      # on TPU, else gather) | gather (XLA
                                       # page-table gather) | fused (Pallas
                                       # in-kernel page walk)
     kv_dtype: str = "fp16"            # paged KV page storage: fp16 (compute-

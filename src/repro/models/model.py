@@ -139,7 +139,7 @@ def forward(
     PagedKVCache pools (the serving runtime's paged layout); loop-invariant
     across the layer scan, like the hoisted causal bias.  How the paged
     read executes — the XLA gather or the fused Pallas page-walk kernel —
-    is ``cfg.paged_attn``, resolved per shape bucket by the engine's
+    is ``cfg.paged_attn``, resolved by platform in the engine's
     attention-backend registry (``core.engine.select_attn_backend``).
 
     PagedKVCache pools ride the layer scan's carry, whole and stacked over

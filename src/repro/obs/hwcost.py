@@ -14,9 +14,8 @@ This is the Lynchpin-style discipline for in-memory VMM claims: evaluated
 per workload, component-attributed, reproducible — not a single design
 point.  The model is built once at ``freeze_model`` / ``from_artifact``,
 recorded in the artifact manifest, and is the ONE source of geometry truth
-shared by ``da_memory_report``, the planner's analytic fallback
-(:func:`da_design`), ``benchmarks/roofline_table.py`` and
-``metrics()["hw"]``.
+shared by ``da_memory_report`` (:func:`da_design`),
+``benchmarks/roofline_table.py`` and ``metrics()["hw"]``.
 
 Accounting conventions (documented, test-asserted):
 
@@ -51,8 +50,8 @@ DA_W_BITS = 8
 def da_design(k: int, n: int, x_bits: int = 8, group_size: int = 8,
               w_bits: int = DA_W_BITS) -> DADesign:
     """THE layer-geometry → DA engine mapping (single source of truth —
-    the freeze planner's analytic fallback and every report go through
-    here, never through ad-hoc ``DADesign(...)`` construction)."""
+    every report goes through here, never through ad-hoc
+    ``DADesign(...)`` construction)."""
     return DADesign(k=k, n=n, w_bits=w_bits, x_bits=x_bits,
                     base_group=group_size)
 

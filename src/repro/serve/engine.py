@@ -16,9 +16,9 @@ precondition).
   the same jitted call.
 
 DA quantization is wired through the artifact pipeline (repro.core.freeze):
-pass ``da_mode`` — ``"auto"`` plans a backend/group-size/LUT decision per
-layer from measured + analytic costs; a registered backend name pins every
-layer — and float params are frozen into PackedWeights artifacts whose every
+pass ``da_mode`` — ``"auto"`` plans a backend/LUT decision per layer by the
+engine's one backend rule; a registered backend name pins every layer — and
+float params are frozen into PackedWeights artifacts whose every
 linear runs the multiplier-free datapath.  ``ServeEngine.from_artifact``
 boots the same engine from a persisted artifact directory with zero float
 weights and zero re-packing; ``save_artifact`` writes one.
@@ -335,8 +335,8 @@ class ServeEngine:
     ):
         # paged_attn: the paged-attention read backend — "gather" (XLA
         # page-table gather), "fused" (Pallas in-kernel page walk; interpret
-        # mode off-TPU) or "auto" (cost-table / platform dispatch per shape
-        # bucket).  None inherits cfg.paged_attn.  Decoded tokens are
+        # mode off-TPU) or "auto" (fused on TPU, gather elsewhere).  None
+        # inherits cfg.paged_attn.  Decoded tokens are
         # bit-identical across backends at the default float32 softmax.
         # kv_dtype: KV page precision — "fp16" (compute-dtype pages, today's
         # layout), "int8" or "int4" (quantized codes with in-page dequant
@@ -355,12 +355,12 @@ class ServeEngine:
         # refcounted pages with copy-on-write keep decoded tokens
         # bit-identical to caching off.
         # da_mode: freeze float params through the DA artifact pipeline
-        # ("auto" plans a backend per layer from measured + analytic costs;
-        # a registered backend name pins every layer).  Params that already
-        # carry PackedWeights leaves (a loaded artifact) are never re-packed.
-        # da_pin_modes=False keeps runtime shape dispatch on the frozen
-        # artifact (prefill and decode may pick different backends) instead
-        # of baking in the decode-bucket plan.
+        # ("auto" plans a backend per layer by the engine's backend rule at
+        # m = batch_size; a registered backend name pins every layer).
+        # Params that already carry PackedWeights leaves (a loaded artifact)
+        # are never re-packed.  da_pin_modes=False keeps runtime shape
+        # dispatch on the frozen artifact (prefill and decode may pick
+        # different backends) instead of baking in the decode-batch plan.
         # trace: turn on the structured event recorder (request lifecycle +
         # scheduler tick spans; export with write_trace()).  The metrics
         # registry is always on — tracing is the opt-in half.  obs= hands in

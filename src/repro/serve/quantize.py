@@ -3,9 +3,8 @@
 The old surface — ``freeze_model_da(params, cfg, mode=...)`` threading one
 execution mode through every layer — is preserved for existing call sites,
 but it now delegates to the artifact pipeline's planner: under
-``mode="auto"`` each layer gets its own (backend, group size, lut-or-not)
-plan from measured autotune timings with the analytic hardware model as the
-cache-less fallback.  New code should use :func:`repro.core.freeze.freeze_model`
+``mode="auto"`` each layer gets its own (backend, lut-or-not) plan from the
+engine's one backend rule.  New code should use :func:`repro.core.freeze.freeze_model`
 directly — it returns the full :class:`~repro.core.freeze.DAArtifact`
 (plan included) which :func:`repro.core.freeze.save_artifact` persists for
 serve-from-disk boots.

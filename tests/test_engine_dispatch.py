@@ -1,145 +1,65 @@
-"""The engine's shape-aware ``mode="auto"`` dispatch: every shape bucket
-resolves to a registered, eligible backend; a missing autotune cache degrades
-to the deterministic heuristic; unknown modes fail loudly.
+"""The engine's ``mode="auto"`` policy: one rule picks the backend from the
+activation rows, the contraction and whether the artifact carries LUTs; the
+freeze planner applies the same rule; unknown modes fail loudly.
 """
-import json
-import warnings
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import engine
 from repro.core.da import DAConfig
 from repro.core.engine import (
-    BUCKET_SHAPES,
     canonical_mode,
     da_matmul,
     get_backend,
-    load_cost_table,
+    lut_cells,
     pack_quantized,
     pack_weights,
     registered_backends,
     select_backend,
-    set_cost_table,
-    shape_bucket,
 )
+from repro.core.freeze import plan_layer
 
-
-@pytest.fixture(autouse=True)
-def _isolate_cost_table():
-    """Each test installs its own cost table; restore lazy state afterwards."""
-    yield
-    set_cost_table(None)
-
-
-def test_bucketing_is_total_and_stable():
-    """shape_bucket covers all of (M, K, N, bits) space and its 9 cells match
-    the representative shapes the autotune benchmark times."""
-    cfg_bits = 8
-    seen = set()
-    for m in (1, 8, 9, 256, 257, 4096):
-        for k, n in ((8, 8), (128, 128), (512, 512), (4096, 4096)):
-            b = shape_bucket(m, k, n, cfg_bits)
-            mb, kb, bits = b.split(":")
-            assert mb in {"dec", "mid", "big"} and kb in {"s", "m", "l"}
-            assert bits == f"b{cfg_bits}"
-            seen.add(b)
-    assert len(seen) == 9
-    assert seen == {
-        shape_bucket(m, k, n, cfg_bits) for m, k, n in BUCKET_SHAPES.values()
-    }
+#: One representative (M, K, N) per regime: decode-like (M ≤ 8), mid and big
+#: rows against small (K·N ≤ 2^14), mid (≤ 2^20) and large weight matrices.
+SHAPES = {
+    "dec:s": (4, 64, 128),
+    "dec:m": (4, 512, 1024),
+    "dec:l": (4, 2048, 2048),
+    "mid:s": (64, 64, 128),
+    "mid:m": (64, 512, 1024),
+    "mid:l": (64, 2048, 2048),
+    "big:s": (512, 64, 128),
+    "big:m": (512, 512, 1024),
+    "big:l": (512, 2048, 2048),
+}
 
 
 @pytest.mark.parametrize("has_luts", [True, False])
-@pytest.mark.parametrize("cell", sorted(BUCKET_SHAPES))
+@pytest.mark.parametrize("cell", sorted(SHAPES))
 def test_auto_returns_registered_backend_for_every_bucket(cell, has_luts):
-    """No cache: the fallback policy yields a registered, eligible backend
-    for every shape bucket, with and without LUTs."""
-    set_cost_table({})  # simulate absent autotune cache
-    m, k, n = BUCKET_SHAPES[cell]
+    """The rule yields a registered, eligible DA backend for every
+    representative shape, with and without LUTs."""
+    m, k, n = SHAPES[cell]
     cfg = DAConfig(x_signed=True)
     name = select_backend(m, k, n, cfg, has_luts=has_luts)
     spec = registered_backends()[name]
     assert spec.is_da and spec.supports(cfg, has_luts)
 
 
-def test_auto_follows_measured_costs():
-    """With a cost table present, auto picks the cheapest eligible backend —
-    and ignores measurements for ineligible ones (LUT modes without LUTs)."""
+@pytest.mark.parametrize("luts_fit", [True, False])
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_freeze_plan_matches_dispatch(cell, luts_fit):
+    """The freeze planner and call-time dispatch pick the same backend: a
+    layer planned at m_hint=M serves under the backend an M-row call
+    against the packed artifact would dispatch to, LUTs fitting or not."""
+    m, k, n = SHAPES[cell]
     cfg = DAConfig(x_signed=True)
-    bucket = shape_bucket(4, 64, 128, cfg.x_bits)
-    set_cost_table({bucket: {"onehot": 1.0, "bitplane": 5.0, "int8": 0.1}})
-    # int8 is measured cheapest but is not a DA backend: never auto-picked
-    assert select_backend(4, 64, 128, cfg, has_luts=True) == "onehot"
-    # without LUTs the measured winner is ineligible → next eligible measured
-    assert select_backend(4, 64, 128, cfg, has_luts=False) == "bitplane"
-
-
-def test_auto_fallback_when_bucket_unmeasured():
-    """A cache that lacks the bucket behaves exactly like no cache."""
-    cfg = DAConfig(x_signed=True)
-    other = shape_bucket(512, 2048, 2048, cfg.x_bits)
-    set_cost_table({other: {"bitplane": 1.0}})
-    with_table = select_backend(4, 64, 128, cfg, has_luts=True)
-    set_cost_table({})
-    without = select_backend(4, 64, 128, cfg, has_luts=True)
-    assert with_table == without
-
-
-def test_bucket_miss_warns_once_per_bucket_and_backend():
-    """A tuned cache that misses the dispatched bucket warns ONCE per
-    (bucket, fallback backend) — a decode loop hits the same bucket every
-    token and must not spam — while a wholly absent cache stays silent."""
-    import warnings as _warnings
-
-    cfg = DAConfig(x_signed=True)
-    other = shape_bucket(512, 2048, 2048, cfg.x_bits)
-    set_cost_table({other: {"bitplane": 1.0}})
-    with pytest.warns(UserWarning, match="no timings"):
-        first = select_backend(4, 64, 128, cfg, has_luts=True)
-    with _warnings.catch_warnings():
-        _warnings.simplefilter("error")  # any repeat warning would raise
-        assert select_backend(4, 64, 128, cfg, has_luts=True) == first
-        # a different bucket gets its own single warning
-        with pytest.warns(UserWarning, match="no timings"):
-            select_backend(300, 64, 128, cfg, has_luts=True)
-    # installing a fresh table resets the dedup set
-    set_cost_table({other: {"bitplane": 1.0}})
-    with pytest.warns(UserWarning, match="no timings"):
-        select_backend(4, 64, 128, cfg, has_luts=True)
-    # no cache at all → heuristic silently (the engine never requires tuning)
-    set_cost_table({})
-    with _warnings.catch_warnings():
-        _warnings.simplefilter("error")
-        select_backend(4, 64, 128, cfg, has_luts=True)
-
-
-def test_cost_table_loads_from_json(tmp_path):
-    """The autotune JSON cache round-trips through the loader; junk entries
-    (unknown backends, malformed costs) are dropped, not fatal."""
-    cfg = DAConfig(x_signed=True)
-    bucket = shape_bucket(4, 64, 128, cfg.x_bits)
-    p = tmp_path / "autotune.json"
-    p.write_text(json.dumps({
-        "version": 1, "device": "cpu",
-        "table": {bucket: {"lut": 2.0, "bitplane_stacked": 9.0,
-                           "not_a_backend": 1e-9, "bitplane": "junk"}},
-    }))
-    table = load_cost_table(p)
-    assert table[bucket] == {"lut": 2.0, "bitplane_stacked": 9.0}
-    set_cost_table(table)
-    assert select_backend(4, 64, 128, cfg, has_luts=True) == "lut"
-
-
-def test_cost_table_absent_or_corrupt_is_safe(tmp_path):
-    """Missing and corrupt caches degrade to {} — dispatch still works."""
-    assert load_cost_table(tmp_path / "nope.json") == {}
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert load_cost_table(bad) == {}
-    set_cost_table({})
-    assert select_backend(1, 16, 16, DAConfig(x_signed=True), True)
+    cells = lut_cells(k, n, cfg.group_size)
+    plan = plan_layer(k, n, cfg, m_hint=m,
+                      lut_cell_limit=cells if luts_fit else cells - 1)
+    assert plan.with_luts == luts_fit and plan.source == "rule"
+    assert plan.mode == select_backend(m, k, n, cfg, has_luts=luts_fit)
+    assert plan.mode == ("lut" if luts_fit and m <= 8 else "bitplane_stacked")
 
 
 def test_unknown_mode_rejected_with_clear_error():
@@ -163,7 +83,6 @@ def test_legacy_mode_aliases_canonicalize():
 def test_auto_dispatch_end_to_end_matches_explicit():
     """mode='auto' (the surface serve/engine.py and core/linear.py use)
     produces the same integers as every explicit backend, whatever it picks."""
-    set_cost_table({})
     rng = np.random.default_rng(5)
     x = rng.normal(size=(3, 32)).astype(np.float32)
     w = rng.normal(size=(32, 16)).astype(np.float32)
@@ -181,96 +100,10 @@ def test_packed_auto_respects_lut_cell_limit():
     small = pack_weights(w)                      # 2^8/8 × 2048 cells: fits
     tight = pack_weights(w, lut_cell_limit=100)       # budget too small
     assert small.has_luts and not tight.has_luts
-    set_cost_table({})
     cfg = DAConfig(x_signed=True)
     assert select_backend(4, 64, 32, cfg, small.has_luts) == "lut"
     chosen = select_backend(4, 64, 32, cfg, tight.has_luts)
     assert not registered_backends()[chosen].needs_luts
-
-
-def test_engine_default_cache_path_env(monkeypatch, tmp_path):
-    p = tmp_path / "alt.json"
-    monkeypatch.setenv("REPRO_ENGINE_AUTOTUNE", str(p))
-    assert engine.default_cache_path() == p
-
-
-def test_explicit_path_load_is_read_only(tmp_path):
-    """load_cost_table(path) inspects without redirecting auto dispatch —
-    only default-path loads (or set_cost_table) touch the process table."""
-    installed = {"some:bucket:b8": {"bitplane": 1.0}}
-    set_cost_table(installed)
-    p = tmp_path / "other.json"
-    p.write_text(json.dumps({"device": "cpu", "table": {}}))
-    assert load_cost_table(p) == {}
-    assert load_cost_table() == installed  # process table untouched
-
-
-def test_cost_table_registry_fingerprint_mismatch_warns(tmp_path):
-    """A cache tuned against a different backend registry (renamed/added/
-    removed backends) is ignored with a warning — the heuristic fallback
-    serves dispatch instead of stale rankings or a KeyError."""
-    import jax
-
-    from repro.core.engine import registry_fingerprint
-
-    cfg = DAConfig(x_signed=True)
-    bucket = shape_bucket(4, 64, 128, cfg.x_bits)
-    stale = tmp_path / "stale.json"
-    stale.write_text(json.dumps({
-        "version": 1, "device": jax.default_backend(),
-        "registry": "00000000", "table": {bucket: {"lut": 1.0}},
-    }))
-    with pytest.warns(UserWarning, match="different backend registry"):
-        assert load_cost_table(stale) == {}
-    # a matching fingerprint loads normally; absence of a stamp is accepted
-    # (pre-fingerprint caches keep working)
-    fresh = tmp_path / "fresh.json"
-    fresh.write_text(json.dumps({
-        "version": 1, "device": jax.default_backend(),
-        "registry": registry_fingerprint(), "table": {bucket: {"lut": 1.0}},
-    }))
-    assert load_cost_table(fresh) == {bucket: {"lut": 1.0}}
-
-
-def test_cost_table_unknown_backend_names_warn(tmp_path):
-    """Unknown backend names in a cache are dropped with a warning, and
-    dispatch still resolves (heuristic covers untimed shapes)."""
-    import jax
-
-    cfg = DAConfig(x_signed=True)
-    bucket = shape_bucket(4, 64, 128, cfg.x_bits)
-    p = tmp_path / "renamed.json"
-    p.write_text(json.dumps({
-        "version": 1, "device": jax.default_backend(),
-        "table": {bucket: {"warp_drive": 0.1, "lut": 2.0}},
-    }))
-    with pytest.warns(UserWarning, match="unregistered backends"):
-        table = load_cost_table(p)
-    assert table[bucket] == {"lut": 2.0}
-    set_cost_table(table)
-    assert select_backend(4, 64, 128, cfg, has_luts=True) == "lut"
-
-
-def test_cost_table_rejects_other_device(tmp_path):
-    """A cache tuned on different hardware must not steer dispatch (a
-    TPU-tuned table would send CPU through interpret-mode Pallas)."""
-    import jax
-
-    cfg = DAConfig(x_signed=True)
-    bucket = shape_bucket(4, 64, 128, cfg.x_bits)
-    p = tmp_path / "tuned_elsewhere.json"
-    other = "tpu" if jax.default_backend() != "tpu" else "cpu"
-    p.write_text(json.dumps(
-        {"version": 1, "device": other, "table": {bucket: {"pallas_lut": 0.1}}}
-    ))
-    here = jax.default_backend()
-    with pytest.warns(UserWarning,
-                      match=f"tuned on '{other}' but this process runs on "
-                            f"'{here}'"):
-        assert load_cost_table(p) == {}
-    with warnings.catch_warnings():  # once per cache and device, not per load
-        warnings.simplefilter("error")
-        assert load_cost_table(p) == {}
 
 
 def test_explicit_mode_enforces_capabilities():
@@ -295,17 +128,16 @@ def test_explicit_auto_overrides_packed_mode():
     from repro.core.engine import _resolve_spec
 
     cfg = DAConfig(x_signed=True)
-    bucket = shape_bucket(3, 32, 16, cfg.x_bits)
-    set_cost_table({bucket: {"bitplane_stacked": 1.0, "lut": 50.0}})
-    auto = _resolve_spec("auto", 3, 32, 16, cfg, True, default_mode="lut")
-    assert auto.name == "bitplane_stacked"  # measured winner, not the default
-    deferred = _resolve_spec(None, 3, 32, 16, cfg, True, default_mode="lut")
+    # 16 rows: the rule runs stacked bit-planes even though LUTs exist
+    auto = _resolve_spec("auto", 16, 32, 16, cfg, True, default_mode="lut")
+    assert auto.name == "bitplane_stacked"  # the rule's pick, not the default
+    deferred = _resolve_spec(None, 16, 32, 16, cfg, True, default_mode="lut")
     assert deferred.name == "lut"
     # and the float path accepts both spellings end-to-end
     rng = np.random.default_rng(6)
     w = jnp.asarray(rng.normal(size=(32, 16)), dtype=jnp.float32)
     packed = pack_weights(w, mode="lut")
-    x = jnp.asarray(rng.normal(size=(3, 32)), dtype=jnp.float32)
+    x = jnp.asarray(rng.normal(size=(16, 32)), dtype=jnp.float32)
     np.testing.assert_array_equal(
         np.asarray(da_matmul(x, packed, mode="auto")),
         np.asarray(da_matmul(x, packed)),

@@ -1,6 +1,5 @@
 """The runnable examples stay runnable (fast ones, executed in-process)."""
 import runpy
-import sys
 
 import pytest
 

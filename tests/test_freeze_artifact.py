@@ -1,6 +1,6 @@
 """The DA artifact pipeline: plan → pack → serialize → serve.
 
-Covers the model-level planner (per-layer, measured + analytic fallback),
+Covers the model-level planner (per-layer, the engine's backend rule),
 bit-exact PackedWeights persistence through the checkpoint layer (crc
 verified), and the freeze-once/serve-many end-to-end: an artifact written to
 disk and reloaded in a fresh, template-free path (no float weights in scope)
@@ -17,13 +17,16 @@ import pytest
 
 from repro.checkpoint import ckpt
 from repro.configs.registry import ARCHS
-from repro.core import engine
 from repro.core.da import DAConfig
-from repro.core.engine import PackedWeights, da_matmul, shape_bucket
+from repro.core.engine import (
+    DEFAULT_LUT_LIMIT,
+    PackedWeights,
+    da_matmul,
+    lut_cells,
+)
 from repro.core.freeze import (
     DAArtifact,
     LayerPlan,
-    analytic_costs,
     da_memory_report,
     freeze_model,
     load_artifact,
@@ -35,16 +38,8 @@ from repro.core.freeze import (
 KEY = jax.random.key(0)
 
 
-@pytest.fixture(autouse=True)
-def _isolate_cost_table():
-    """Planner tests install their own cost tables; restore lazy state."""
-    yield
-    engine.set_cost_table(None)
-
-
 def _serve_cfg(**kw):
-    """Tiny qwen3-like serving config with two distinct VMM shape buckets:
-    attention/MLP mats land in dec:s, the lm head (vocab 503) in dec:m."""
+    """Tiny qwen3-like serving config (d_model 64, lm head 64 x 503)."""
     base = dict(
         name="qwen3-tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
         head_dim=16, d_ff=128, vocab=503, param_dtype="float32",
@@ -54,46 +49,33 @@ def _serve_cfg(**kw):
     return dataclasses.replace(ARCHS["qwen3-8b"], **base)
 
 
-def _two_bucket_table(m_hint: int, cfg):
-    """Deterministic cost table: stacked wins the small bucket, lut the
-    lm-head bucket — so a correct per-layer planner MUST differ by shape."""
-    small = shape_bucket(m_hint, cfg.d_model, cfg.d_model, 8)
-    head = shape_bucket(m_hint, cfg.d_model, cfg.vocab, 8)
-    assert small != head, "test premise: two distinct buckets"
-    return {
-        small: {"bitplane_stacked": 1.0, "lut": 50.0, "bitplane": 40.0},
-        head: {"lut": 1.0, "bitplane_stacked": 50.0, "bitplane": 60.0},
-    }
+#: Smallest vocabulary whose 64-wide lm head busts the default LUT budget,
+#: so the rule serves it with stacked bit-planes while the smaller layers of
+#: the same model read their LUTs.
+NO_LUT_VOCAB = DEFAULT_LUT_LIMIT // lut_cells(64, 1, 8) + 1
 
 
 # ---------------------------------------------------------------------------
 # planner
 # ---------------------------------------------------------------------------
 
-def test_plan_layer_measured_beats_analytic():
+def test_plan_layer_follows_the_backend_rule():
+    """The planner applies the engine's rule at m_hint: PMA readout for
+    decode-like batches when the LUTs fit the budget, stacked bit-planes
+    when they do not or when the batch is wider than 8 rows."""
     cfg = DAConfig(x_signed=True)
-    table = {shape_bucket(4, 64, 64, 8): {"bitplane": 1.0, "lut": 9.0}}
-    p = plan_layer(64, 64, cfg, m_hint=4, cost_table=table)
-    assert p.mode == "bitplane" and p.source == "measured"
-    assert p.est_cost == 1.0 and p.with_luts
-
-
-def test_plan_layer_analytic_fallback_uses_hwmodel():
-    """No measurement for the bucket: ranking comes from the analytic
-    hardware model — PMA readout when LUTs exist, stacked bit-planes when
-    the LUT blow-up is over budget."""
-    cfg = DAConfig(x_signed=True)
-    with_luts = plan_layer(64, 64, cfg, m_hint=4, cost_table={})
-    assert with_luts.source == "analytic" and with_luts.mode == "lut"
-    no_luts = plan_layer(64, 64, cfg, m_hint=4, cost_table={},
-                         lut_cell_limit=100)
+    with_luts = plan_layer(64, 64, cfg, m_hint=4)
+    assert with_luts.source == "rule" and with_luts.mode == "lut"
+    assert with_luts.with_luts
+    no_luts = plan_layer(64, 64, cfg, m_hint=4, lut_cell_limit=100)
     assert not no_luts.with_luts and no_luts.mode == "bitplane_stacked"
-    costs = analytic_costs(4, 64, 64, cfg, has_luts=True)
-    assert costs["lut"] < costs["bitplane_stacked"] < costs["bitplane"]
+    wide = plan_layer(64, 64, cfg, m_hint=16)
+    assert wide.with_luts and wide.mode == "bitplane_stacked"
 
 
 def test_plan_model_is_per_layer_not_constant():
-    """The acceptance property: plans differ across layer shapes."""
+    """The acceptance property: plans differ across layer shapes (here the
+    lm head's LUTs bust a budget the attention matrix's fit)."""
     cfg = _serve_cfg()
     params = jax.tree.map(jnp.asarray, {
         "mixer": {"wq": np.random.default_rng(0).normal(
@@ -102,10 +84,41 @@ def test_plan_model_is_per_layer_not_constant():
             size=(cfg.d_model, cfg.vocab)).astype(np.float32)},
     })
     plans = plan_model(params, DAConfig(x_signed=True), m_hint=2,
-                       cost_table=_two_bucket_table(2, cfg))
+                       lut_cell_limit=lut_cells(cfg.d_model, cfg.d_model, 8))
     assert set(plans) == {"mixer/wq", "lm_head/w"}
-    assert plans["mixer/wq"].mode == "bitplane_stacked"
-    assert plans["lm_head/w"].mode == "lut"
+    assert plans["mixer/wq"].mode == "lut"
+    assert plans["lm_head/w"].mode == "bitplane_stacked"
+    assert not plans["lm_head/w"].with_luts
+
+
+@pytest.mark.parametrize("m_hint", [4, 32])
+@pytest.mark.parametrize("arch", ["qwen3-8b", "mistral-nemo-12b"])
+def test_benchmark_widths_plan_bitplane_stacked(arch, m_hint):
+    """At the benchmark configurations' published widths (full depth, shapes
+    only — nothing is allocated) no layer's LUTs fit the default budget, so
+    every DA leaf is planned as stacked bit-planes without LUTs."""
+    from repro.models.model import init_model
+
+    cfg = ARCHS[arch]
+    shapes = jax.eval_shape(lambda: init_model(jax.random.key(0), cfg))
+    plans = plan_model(shapes, DAConfig(x_signed=True), m_hint=m_hint)
+    assert plans
+    for key, plan in plans.items():
+        assert (plan.mode, plan.with_luts) == ("bitplane_stacked", False), key
+
+
+def test_layer_plan_reads_older_manifest_entry():
+    """A manifest entry written by an older build (a cost planner's
+    ``est_cost`` and ``"measured"`` source) still loads: the cost is
+    dropped, the source kept as written."""
+    plan = LayerPlan.from_json({
+        "mode": "bitplane_stacked", "group_size": 8, "with_luts": False,
+        "k": 64, "n": 32, "source": "measured", "est_cost": 1.0,
+        "kv_dtype": None,
+    })
+    assert plan == LayerPlan(mode="bitplane_stacked", group_size=8,
+                             with_luts=False, k=64, n=32, source="measured")
+    assert "est_cost" not in plan.to_json()
 
 
 def test_freeze_model_pinned_mode_matches_legacy():
@@ -123,15 +136,14 @@ def test_pinned_freeze_drops_dead_luts():
     would be dead bytes in every artifact); pin_modes=False keeps feasible
     LUTs so runtime dispatch can still read them at other shapes."""
     cfg = DAConfig(x_signed=True)
-    table = {shape_bucket(4, 64, 64, 8): {"bitplane_stacked": 1.0,
-                                          "lut": 9.0}}
     w = {"wq": jnp.asarray(np.random.default_rng(7).normal(size=(64, 64)),
                            jnp.float32)}
-    pinned = freeze_model(w, cfg, m_hint=4, cost_table=table)
+    # 16 rows: the rule picks stacked bit-planes although the LUTs fit
+    pinned = freeze_model(w, cfg, m_hint=16)
     assert pinned.params["wq"].mode == "bitplane_stacked"
     assert not pinned.params["wq"].has_luts
     assert not pinned.plan["wq"].with_luts
-    loose = freeze_model(w, cfg, m_hint=4, cost_table=table, pin_modes=False)
+    loose = freeze_model(w, cfg, m_hint=16, pin_modes=False)
     assert loose.params["wq"].mode == "auto" and loose.params["wq"].has_luts
 
 
@@ -144,21 +156,6 @@ def test_skip_context_subtrees_stay_float():
     assert not isinstance(art.params["router"]["w"], PackedWeights)
     assert isinstance(art.params["head"]["w"], PackedWeights)
     assert set(art.plan) == {"head/w"}
-
-
-def test_group_size_candidates_recover_luts():
-    """A layer whose LUTs bust the budget at L=8 can shrink its PMAs to
-    L=4 (16-row tables) and keep the readout path — per-layer group size."""
-    cfg = DAConfig(x_signed=True)
-    # 2^8/8 = 32 cells/weight at L=8; 2^4/4 = 4 at L=4. Pick a budget between.
-    k, n = 64, 64
-    limit = 8 * k * n  # admits L=4 (4x), rejects L=8 (32x)
-    p8 = plan_layer(k, n, cfg, cost_table={}, lut_cell_limit=limit)
-    assert not p8.with_luts
-    p48 = plan_layer(k, n, cfg, cost_table={}, lut_cell_limit=limit,
-                     group_size_candidates=(8, 4))
-    assert p48.with_luts and p48.group_size == 4
-    assert p48.mode == "lut"
 
 
 # ---------------------------------------------------------------------------
@@ -297,12 +294,11 @@ def test_serve_from_artifact_matches_in_memory(tmp_path):
     reload it template-free (zero float weights in scope), serve greedy
     decode through ServeEngine, and match the in-memory frozen model's
     tokens.  The plan must be per-layer: at least two layer shapes get
-    different backends."""
+    different backends (the lm head's LUTs bust the budget)."""
     from repro.models.model import init_model
     from repro.serve.engine import Request, ServeEngine
 
-    cfg = _serve_cfg()
-    engine.set_cost_table(_two_bucket_table(2, cfg))
+    cfg = _serve_cfg(vocab=NO_LUT_VOCAB)
     params = init_model(KEY, cfg)
     eng_mem = ServeEngine(cfg, params, batch_size=2, max_len=32,
                           da_mode="auto")
@@ -344,7 +340,6 @@ def test_artifact_plan_survives_roundtrip_with_model_cfg(tmp_path):
     from repro.models.model import init_model
 
     cfg = _serve_cfg(n_layers=2)
-    engine.set_cost_table(_two_bucket_table(2, cfg))
     art = freeze_model(init_model(KEY, cfg), DAConfig(x_signed=True),
                        m_hint=2, model_cfg=cfg)
     d = str(tmp_path / "a")

@@ -7,7 +7,6 @@ pinned here against hand-built HLO snippets plus a real jit lowering.
 """
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.analysis.hlo import (
     HloOp,

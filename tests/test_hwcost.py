@@ -21,7 +21,6 @@ from repro.obs.export import validate_chrome_trace, validate_metrics_json
 from repro.obs.hwcost import (
     HWCOST_VERSION,
     HardwareCostModel,
-    LayerGeom,
     draft_price,
 )
 from repro.obs.metrics import METRICS_SCHEMA_VERSION

@@ -11,7 +11,6 @@ from repro.models.model import (
     count_params,
     forward,
     init_model,
-    lm_loss,
 )
 from repro.train.trainer import TrainConfig, init_state, make_train_step
 

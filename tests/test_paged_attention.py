@@ -11,7 +11,6 @@ engine calls exactly; and the three bugfixes (platform-derived interpret
 default, fp32-exact bk auto-shrink, warm-dense-cache chunked prefill)
 behave as documented."""
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
@@ -20,18 +19,15 @@ import pytest
 
 from repro.configs.registry import ARCHS, reduce_for_smoke
 from repro.core.engine import (
-    attn_shape_bucket,
     da_matmul,
     da_qkv_matmul,
     get_attn_backend,
-    load_cost_table,
     registered_attn_backends,
     select_attn_backend,
-    set_cost_table,
 )
 from repro.kernels.paged_attention import paged_attention
 from repro.models.attention import paged_gather_read
-from repro.models.model import forward, init_model
+from repro.models.model import init_model
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvcache import pages_for, table_width
 
@@ -137,35 +133,12 @@ def test_attn_registry_and_dispatch():
     assert {"gather", "fused"} <= names
     with pytest.raises(ValueError, match="unknown paged-attention"):
         get_attn_backend("nope")
-    # off-TPU heuristic: auto resolves to the gather read
-    assert select_attn_backend("auto", batch=2, t=1, kv_len=64) == "gather"
-    assert select_attn_backend(None, batch=2, t=1, kv_len=64) == "gather"
-    assert select_attn_backend("fused", batch=2, t=1, kv_len=64) == "fused"
-    # a measured attn bucket overrides the heuristic
-    bucket = attn_shape_bucket(2, 1, 64)
-    assert bucket.startswith("attn:dec:")
-    try:
-        set_cost_table({bucket: {"fused": 1.0, "gather": 9.0}})
-        assert select_attn_backend("auto", batch=2, t=1, kv_len=64) == "fused"
-    finally:
-        set_cost_table(None)
-
-
-def test_cost_table_keeps_attn_buckets(tmp_path):
-    """load_cost_table must not drop attn backend names as 'unregistered
-    VMM backends' — attn:* buckets are filtered against the attn registry."""
-    p = tmp_path / "autotune.json"
-    p.write_text(json.dumps({
-        "device": jax.default_backend(),
-        "table": {
-            "attn:dec:s": {"fused": 1.0, "gather": 2.0, "bogus": 3.0},
-            "dec:s:b8": {"bitplane": 4.0},
-        },
-    }))
-    with pytest.warns(UserWarning, match="bogus"):
-        table = load_cost_table(p)
-    assert table["attn:dec:s"] == {"fused": 1.0, "gather": 2.0}
-    assert table["dec:s:b8"] == {"bitplane": 4.0}
+    # off-TPU: auto resolves to the gather read; explicit names pass through
+    assert select_attn_backend("auto") == "gather"
+    assert select_attn_backend(None) == "gather"
+    assert select_attn_backend("fused") == "fused"
+    with pytest.raises(ValueError, match="unknown paged-attention"):
+        select_attn_backend("nope")
 
 
 # ---------------------------------------------------------------------------

@@ -14,8 +14,7 @@ import pytest
 from repro.configs.registry import ARCHS, reduce_for_smoke
 from repro.core import engine
 from repro.core.da import DAConfig, truncate_codes
-from repro.core.engine import da_matmul, da_vmm, pack_quantized, pack_weights, \
-    set_cost_table
+from repro.core.engine import da_matmul, da_vmm, pack_quantized, pack_weights
 from repro.models.model import init_model
 from repro.serve.engine import Request, ServeEngine
 from repro.spec import SpecConfig, breakeven_acceptance, greedy_accept
@@ -81,7 +80,6 @@ def test_da_vmm_partial_bits_equals_masked_codes(mode, rng):
 
 
 def test_da_matmul_x_bits_eff_and_override_context(rng):
-    set_cost_table({})
     w = jnp.asarray(rng.normal(size=(32, 16)), dtype=jnp.float32)
     packed = pack_weights(w)
     x = jnp.asarray(rng.normal(size=(3, 32)), dtype=jnp.float32)
@@ -97,7 +95,6 @@ def test_da_matmul_x_bits_eff_and_override_context(rng):
             y4, np.asarray(jax.jit(lambda a: da_matmul(a, packed))(x)))
     # and full precision is restored outside the context
     np.testing.assert_array_equal(y_full, np.asarray(da_matmul(x, packed)))
-    set_cost_table(None)
 
 
 # ---------------------------------------------------------------------------
