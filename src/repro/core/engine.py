@@ -624,8 +624,8 @@ class AttnBackendSpec:
     already-written pool, ragged-masked by ``tpos``; a period-stacked 5-D
     pool is read at ``layer``.  Quantized pools (int8 codes / packed int4)
     pass their in-page dequant scales ``[(L,)P, ps, kv, 1]``; both backends
-    dequantize with the same elementwise formula (``kv_quant``), so decoded
-    tokens stay bit-identical across backends on quantized pages too.
+    dequantize with the same elementwise formula (``kv_quant``), so they
+    attend over bitwise the same keys and values on quantized pages too.
     """
 
     name: str
@@ -686,8 +686,8 @@ def _gather_attn_backend(q, k_pool, v_pool, page_table, tpos, **kw):
 
 @register_attn_backend(
     "fused",
-    description="Pallas kernel: in-kernel page walk, per-head softmax "
-    "(interpret off-TPU)",
+    description="Pallas kernel: walk of each row's live key blocks, online "
+    "softmax per KV head (interpret off-TPU)",
 )
 def _fused_attn_backend(q, k_pool, v_pool, page_table, tpos, **kw):
     from repro.kernels.paged_attention import paged_attention

@@ -336,8 +336,8 @@ class ServeEngine:
         # paged_attn: the paged-attention read backend — "gather" (XLA
         # page-table gather), "fused" (Pallas in-kernel page walk; interpret
         # mode off-TPU) or "auto" (fused on TPU, gather elsewhere).  None
-        # inherits cfg.paged_attn.  Decoded tokens are
-        # bit-identical across backends at the default float32 softmax.
+        # inherits cfg.paged_attn.  The two reads agree to a few ulps (the
+        # fused one sums each row's keys block by block).
         # kv_dtype: KV page precision — "fp16" (compute-dtype pages, today's
         # layout), "int8" or "int4" (quantized codes with in-page dequant
         # scales).  None inherits cfg.kv_dtype.  kv_dtypes overrides per

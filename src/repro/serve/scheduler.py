@@ -383,6 +383,10 @@ class PagedScheduler:
             "sched_device_calls", "device calls issued, by phase")
         self._c_d2h = reg.counter(
             "sched_d2h_bytes", "bytes copied device-to-host, by phase")
+        self._c_attn_pages = reg.counter(
+            "sched_attn_pages", "pages named by each call's real rows "
+            "(kind=live) and page slots of its table, rows x width "
+            "(kind=table), by phase")
         self._c_draft_steps = reg.counter(
             "spec_draft_steps", "draft-model steps issued")
         self._c_verify_steps = reg.counter(
@@ -898,6 +902,9 @@ class PagedScheduler:
         poss = {i: range(l.pos, l.pos + plan[i]) for _, i, l in rows}
         if self.analysis_debug:
             self._submit_plan(phase, rows, poss)
+        self._c_attn_pages.inc(sum(len(l.pages) for _, _, l in rows),
+                               phase=phase, kind="live")
+        self._c_attn_pages.inc(n_rows * self.W, phase=phase, kind="table")
         args = self._pack_rows(rows, toks, poss, n_rows, t_step, self.cfg)
         if self.greedy:
             return args

@@ -26,7 +26,7 @@ from repro.core.freeze import da_memory_report, freeze_model, load_artifact, \
     save_artifact
 from repro.kernels.paged_attention import paged_attention
 from repro.models import kv_quant as kvq
-from repro.models.attention import PagedKVCache, paged_gather_read
+from repro.models.attention import PagedKVCache
 from repro.models.model import init_model
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvcache import (
@@ -100,7 +100,7 @@ def test_kv_format_inference_and_mismatch():
 
 
 # ---------------------------------------------------------------------------
-# backend bit-parity on quantized pools (the PR-6 guarantee, extended)
+# backend parity on quantized pools (the PR-6 guarantee, to a few ulps)
 # ---------------------------------------------------------------------------
 
 
@@ -117,21 +117,26 @@ def _quantized_paged_case(rng, fmt, t, lens, ps=8, n_pages=12):
 @pytest.mark.parametrize("fmt", ["int8", "int4"])
 @pytest.mark.parametrize("t", [1, 4])
 def test_fused_bitwise_equals_gather_quantized(fmt, t):
-    """Fused kernel == gather read bit-for-bit on quantized pools: the scale
-    pages ride the same scalar-prefetch page walk and dequantization uses
-    the gather path's exact elementwise formula."""
+    """Fused kernel == gather read on quantized pools within 2 ulps of the
+    output dtype (``assert_within_ulps``), over the fp parity case's rows
+    (``PARITY_LENS``): the scale slivers ride the same page copies and
+    dequantization uses the gather path's exact elementwise formula; the
+    online softmax's block order keeps the reads from bitwise equality, so
+    the gather read sums in that order too (``blocked_gather_read``)."""
+    from test_paged_attention import (PARITY_LENS, PS, assert_within_ulps,
+                                      blocked_gather_read)
+
     rng = np.random.default_rng(0)
-    ps = 8
     q, kc, ks, vc, vs, tbl, tpos = _quantized_paged_case(
-        rng, fmt, t, lens=[ps - 1, ps, 2 * ps + 3], ps=ps)
-    ref = paged_gather_read(q, kc, vc, tbl, tpos, k_scale=ks, v_scale=vs)
-    out = paged_attention(q, kc, vc, tbl, tpos, k_scale=ks, v_scale=vs)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+        rng, fmt, t, lens=PARITY_LENS, ps=PS)
+    kw = dict(k_scale=ks, v_scale=vs)
+    assert_within_ulps(blocked_gather_read(q, kc, vc, tbl, tpos, **kw),
+                       paged_attention(q, kc, vc, tbl, tpos, **kw))
 
 
 def test_fused_quantized_ignores_unreferenced_pages():
     """NaN-poisoning the SCALES (not just the codes) of pages no table names
-    must not change the fused output — the walk DMAs neither."""
+    must not change the fused output — the walk copies neither."""
     rng = np.random.default_rng(2)
     q, kc, ks, vc, vs, tbl, tpos = _quantized_paged_case(
         rng, "int8", 1, lens=[9, 17], ps=8)

@@ -5,7 +5,8 @@ attached) v5e chip by the TPU compiler, which refuses what interpret mode
 accepts — blocks off the (8, 128) tiling, accumulators narrower than 32
 bits, loads of types the chip lacks. Shapes are those the served qwen3-8b
 path hits: batch 8, 32 query / 8 KV heads of width 128, 16-token pages over
-a 1024-token context, and the DA VMMs of one full-width layer.
+a 1024-token context (and the fused read at 8192), and the DA VMMs of one
+full-width layer.
 
 The topology is described inside a module fixture (never at import): only
 the test worker that runs this file loads the TPU compiler.
@@ -64,13 +65,20 @@ def _compile(fn, *shapes):
 
 
 @pytest.mark.parametrize("t", [1, 16])
-@pytest.mark.parametrize("kv_dtype", ["fp16", "int8", "int4"])
-def test_fused_paged_attention_compiles(one_chip, kv_dtype, t):
+@pytest.mark.parametrize("kv_dtype,max_len", [
+    pytest.param("fp16", MAX_LEN, id="fp16"),
+    pytest.param("int8", MAX_LEN, id="int8"),
+    pytest.param("int4", MAX_LEN, id="int4"),
+    pytest.param("fp16", 8192, id="fp16-8192"),
+])
+def test_fused_paged_attention_compiles(one_chip, kv_dtype, max_len, t):
+    """At ``max_len`` 8192 too: the kernel's VMEM holds a block of keys, not
+    the row's whole table, so it fits whatever the context."""
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    n_pages = 1 + B * pages_for(MAX_LEN, PS)
-    w = table_width(MAX_LEN, PS)
+    n_pages = 1 + B * pages_for(max_len, PS)
+    w = table_width(max_len, PS)
     pool = (n_pages, PS, KV, HD)
     q, table, tpos = (s((B, t, H, HD), jnp.bfloat16), s((B, w), jnp.int32),
                       s((B, t), jnp.int32))
