@@ -4,6 +4,8 @@
   configuration   bench/configs/<config>.json (sizes, source, serving)
   traffic mix     bench/traffic/<traffic>.json
   reference       bench/references/<reference>.py, named by the config
+  architecture    bench/architectures/<reference>.py: the config's layer
+                  groups, leaves, served tree layout and work per call
   limits          bench/limits/<cell>.json (the numbers ``correct`` holds)
   per-layer       bench/layer_metrics/<metric>.py, one reader each
   kernel work     bench/work/<kernel>.py, ops and bytes from shapes
@@ -83,6 +85,12 @@ def work(kernel: str):
 
 def reference(name: str):
     return _module(BENCH / "references" / f"{_check(name)}.py", "ref")
+
+
+def architecture(name: str):
+    """What the benchmark knows of a model's shape, named by the config's
+    ``reference`` key (its module)."""
+    return _module(BENCH / "architectures" / f"{_check(name)}.py", "arch")
 
 
 def metrics_of(bench: Dict, section: str, cell_name: str) -> List[Dict]:

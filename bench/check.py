@@ -6,9 +6,9 @@ the seed, some hundreds of served tokens in all — is run through the
 configuration's reference (``bench/references/<name>.py``) over prompt ++
 served tokens. Each served token is greedy, so the reference's logit for
 it should sit at the reference's best; the number compared is the widest
-gap, over every sampled token, by which it lies below that best. The
-limit per cell is in ``bench/limits/<cell>.json`` with the readings it was
-set from.
+gap, over every sampled token, by which it lies below that best (+inf for
+an id outside the vocabulary). The limit per cell is in
+``bench/limits/<cell>.json`` with the readings it was set from.
 """
 from __future__ import annotations
 
@@ -84,11 +84,21 @@ def reference_gaps(samples: List[Tuple[np.ndarray, List[int]]], model: Dict,
     hs = ref.hidden_states(seqs, model,
                            lambda li: weights.layer_f32(seed, model, li),
                            ends, pad_to)
-    out = [ref.served_gaps(h, len(p), g, ends["head"])
+    out = [_outside_vocab_is_inf(
+               ref.served_gaps(h, len(p), g, ends["head"]), g,
+               model["vocab_size"])
            for h, (p, g) in zip(hs, samples)]
     del ends, hs
     gc.collect()
     return out
+
+
+def _outside_vocab_is_inf(gaps: np.ndarray, served: List[int],
+                          vocab: int) -> np.ndarray:
+    """A served id outside the vocabulary has no logit: its gap is +inf,
+    not the NaN a lookup past the head's last column reads."""
+    ids = np.asarray(served, np.int64)
+    return np.where((ids < 0) | (ids >= vocab), np.inf, gaps)
 
 
 def judge(gaps: List[np.ndarray], bad: int, failed: int,

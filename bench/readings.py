@@ -2,13 +2,15 @@
 
 A reader (``bench/layer_metrics/<metric>.py``) gets a :class:`RunView` and
 returns a number, or None where the window holds nothing for it to read.
-The logical work of the window is counted here from the engine's calls and
-the kernels' work functions (``bench/work/``), the same whatever backend
-ran it.
+The logical work of the window is counted here from the engine's calls,
+the products and reads the configuration's architecture names for each
+(``bench/architectures/``), and the kernels' work functions
+(``bench/work/``), the same whatever backend ran it.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 from bench import catalog, tracing
@@ -29,70 +31,43 @@ class RunView:
     def window_s(self) -> float:
         return self.window[1] - self.window[0]
 
-    # -- shapes ------------------------------------------------------------
-    def da_shapes(self) -> List[Tuple[int, int]]:
-        """(K, N) of each DA product of one decoder layer: fused QKV, O,
-        up, gate, down."""
-        m = self.model
-        d, ff = m["hidden_size"], m["intermediate_size"]
-        qd = m["num_attention_heads"] * m["head_dim"]
-        kvd = m["num_key_value_heads"] * m["head_dim"]
-        return [(d, qd + 2 * kvd), (qd, d), (d, ff), (d, ff), (ff, d)]
-
-    def attn_shape(self) -> Dict[str, int]:
-        m = self.model
-        return dict(heads=m["num_attention_heads"],
-                    kv=m["num_key_value_heads"], hd=m["head_dim"],
-                    page_size=int(self.serving["page_size"]))
+    @functools.cached_property
+    def arch(self):
+        """The configuration's architecture module: the products and reads
+        of each call."""
+        return catalog.architecture(self.model["reference"])
 
     # -- logical work ------------------------------------------------------
     def da_least_seconds(self) -> float:
         """Roofline least time of every DA product of every call."""
         da = catalog.work("da_vmm")
-        layers = self.model["num_hidden_layers"]
-        d, vocab = self.model["hidden_size"], self.model["vocab_size"]
         total = 0.0
         for c in self.calls or []:
-            m = sum(n for n, _ in c.rows)
-            if m:
-                for k, n in self.da_shapes():
-                    ops, nb = da.work(m, k, n)
-                    total += layers * least_time(
-                        ops, nb, self.peaks["int8_ops"],
-                        self.peaks["hbm_bytes_per_s"])
-            if c.head_rows:
-                ops, nb = da.work(c.head_rows, d, vocab)
+            for m, k, n in self.arch.da_products(self.model, c):
+                ops, nb = da.work(m, k, n)
                 total += least_time(ops, nb, self.peaks["int8_ops"],
                                     self.peaks["hbm_bytes_per_s"])
         return total
 
     def attn_least_seconds(self) -> float:
         """Roofline least time of every paged-attention read of every call
-        (each call reads once per layer)."""
-        pa = catalog.work("paged_attn")
-        layers = self.model["num_hidden_layers"]
+        (the architecture sums a call's reads over its layers)."""
         total = 0.0
         for c in self.calls or []:
             if c.rows:
-                fl, nb = pa.work(c.rows, **self.attn_shape())
-                total += layers * least_time(
-                    fl, nb, self.peaks["bf16_flops"],
-                    self.peaks["hbm_bytes_per_s"])
+                fl, nb = self.arch.attn_reads(self.model, self.serving, c)
+                total += least_time(fl, nb, self.peaks["bf16_flops"],
+                                    self.peaks["hbm_bytes_per_s"])
         return total
 
     def logical_ops(self) -> Tuple[float, float]:
         """(linear int ops, attention flops) the window's tokens need."""
         da = catalog.work("da_vmm")
-        pa = catalog.work("paged_attn")
-        layers = self.model["num_hidden_layers"]
-        d, vocab = self.model["hidden_size"], self.model["vocab_size"]
         lin = att = 0.0
         for c in self.calls or []:
-            m = sum(n for n, _ in c.rows)
-            lin += layers * sum(da.work(m, k, n)[0]
-                                for k, n in self.da_shapes())
-            lin += da.work(c.head_rows, d, vocab)[0]
-            att += layers * pa.work(c.rows, **self.attn_shape())[0]
+            lin += sum(da.work(m, k, n)[0]
+                       for m, k, n in self.arch.da_products(self.model, c))
+            att += self.arch.attn_reads(self.model, self.serving, c)[0]
         return lin, att
 
     # -- trace ---------------------------------------------------------------

@@ -1,7 +1,8 @@
 """The system under test, as the benchmark drives it.
 
 Everything the benchmark takes from the program passes through here: the
-model configuration type, ``ServeEngine`` and its ``Request``, the engine's
+model configuration type (built by the configuration's architecture,
+``bench/architectures/``), ``ServeEngine`` and its ``Request``, the engine's
 own trace events (``trace=True``), and the HLO text of its compiled step,
 whose ``op_name`` metadata carries the kernels' named scopes.
 """
@@ -12,36 +13,19 @@ import dataclasses
 from collections import defaultdict
 from typing import Dict, List, Sequence, Tuple
 
-#: a reduced model for the CPU rehearsal: same family, tiny widths
-REHEARSAL_MODEL = {"hidden_size": 64, "intermediate_size": 128,
-                   "num_attention_heads": 4, "num_key_value_heads": 2,
-                   "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 97}
-REHEARSAL_SERVING = {"batch": 4, "max_len": 64, "page_size": 8}
+from bench import catalog
 
 
 def rehearsal_model(model: Dict, serving: Dict) -> Tuple[Dict, Dict]:
-    return {**model, **REHEARSAL_MODEL}, {**serving, **REHEARSAL_SERVING}
+    """The configuration and serving settings cut to the CPU rehearsal's
+    tiny size, as the configuration's architecture cuts them."""
+    return catalog.architecture(model["reference"]).rehearsal(model, serving)
 
 
 def model_config(model: Dict):
-    """The program's ModelConfig for a configuration file; keys the file
-    does not set keep the program's defaults."""
-    from repro.models.config import ModelConfig
-
-    return ModelConfig(
-        name=model["name"], family="dense",
-        n_layers=int(model["num_hidden_layers"]),
-        d_model=int(model["hidden_size"]), vocab=int(model["vocab_size"]),
-        n_heads=int(model["num_attention_heads"]),
-        n_kv_heads=int(model["num_key_value_heads"]),
-        head_dim=int(model["head_dim"]),
-        qk_norm=bool(model.get("qk_norm", False)),
-        rope_theta=float(model["rope_theta"]),
-        d_ff=int(model["intermediate_size"]), mlp_act="swiglu",
-        norm_eps=float(model["rms_norm_eps"]),
-        param_dtype=model.get("torch_dtype", "bfloat16"),
-        compute_dtype=model.get("torch_dtype", "bfloat16"),
-    )
+    """The program's ModelConfig for a configuration file, as the
+    configuration's architecture builds it."""
+    return catalog.architecture(model["reference"]).model_config(model)
 
 
 def build_engine(cfg, params, serving: Dict, trace: bool):

@@ -13,13 +13,16 @@ from bench import catalog, sut, weights
 TINY = dict(hidden_size=64, intermediate_size=96, num_attention_heads=4,
             num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
             vocab_size=101)
+#: each configuration at the tiny size; mistral-nemo-12b keeps its q_dim
+#: (heads x head_dim) below d_model, as published (4096 < 5120)
+TINY_OF = {"qwen3-8b": TINY, "mistral-nemo-12b": {**TINY, "head_dim": 12}}
 
 
-@pytest.mark.parametrize("config", ["qwen3-8b"])
+@pytest.mark.parametrize("config", sorted(TINY_OF))
 def test_reference_matches_program_float_forward(config):
     from repro.models.model import forward
 
-    model = {**catalog.config(config), **TINY}
+    model = {**catalog.config(config), **TINY_OF[config]}
     ref = catalog.reference(model["reference"])
     seed = 2**31 + 17
     params = weights.make_params(seed, model, dtype="float32")
